@@ -161,8 +161,9 @@ def bench_kernels() -> None:
     timings only; see EXPERIMENTS §Perf for the TPU roofline story)."""
     import jax.numpy as jnp
     from repro.kernels import ops
+    interp = not ops.on_tpu()       # the interpreter off-TPU, by name
     x = jnp.asarray(common.RNG.normal(0, 0.05, (64, 4096)), jnp.bfloat16)
-    us = timeit(lambda v: ops.histogram(v), x, iters=3)
+    us = timeit(lambda v: ops.histogram(v, interpret=interp), x, iters=3)
     emit("kernel.exp_histogram.256k", us, "vs ref: bit-exact (tests)")
     us = timeit(lambda v: fixed.compress(v), x, iters=3)
     emit("kernel.fw_compress.256k", us,
@@ -171,7 +172,9 @@ def bench_kernels() -> None:
     from repro.kernels import ops as kops
     sm, pl, d, _ = kops.compress_weight(w)
     xa = jnp.asarray(common.RNG.normal(0, 1, (128, 512)), jnp.bfloat16)
-    us = timeit(lambda a: kops.matmul_compressed(a, sm, pl, d), xa, iters=3)
+    us = timeit(lambda a: kops.matmul_compressed(a, sm, pl, d,
+                                                 interpret=interp),
+                xa, iters=3)
     emit("kernel.decompress_matmul.128x512x512", us, "fused JIT decode")
 
 
@@ -660,8 +663,9 @@ def bench_decode_kernel() -> None:
     scale = hd ** -0.5
 
     fused = jax.jit(lambda q_: kops.decode_attend_paged(
-        q_, cts.signman, cts.planes, cts.dict_syms, cts.esc_raw, None, ring,
-        pt, lengths, 0, kops.WINDOW_NONE, k=5, hkv=hkv, hd=hd, kv_idx=kv_idx,
+        q_, cts.signman, cts.planes, cts.dict_syms, cts.esc_pos, cts.esc_raw,
+        None, ring, pt, lengths, 0, kops.WINDOW_NONE, k=5, hkv=hkv, hd=hd,
+        kv_idx=kv_idx,
         scale=scale, tp=1, interpret=not kops.on_tpu())[0])
     pure = jax.jit(lambda q_: kref.paged_decode_attend_ref(
         q_, jax.vmap(fixed.decompress)(cts), pt, lengths, ring,

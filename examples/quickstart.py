@@ -37,13 +37,14 @@ exact = bool(jnp.array_equal(jax.lax.bitcast_convert_type(x, jnp.uint16),
 print(f"\nLEXI-FW roundtrip bit-exact: {exact}; wire ratio "
       f"{ct.ratio():.3f}x; escapes {int(ct.n_escapes)}")
 
-# --- 4. the Pallas kernels (interpret mode on CPU) -------------------------
-hist = ops.histogram(x)
+# --- 4. the Pallas kernels (compiled on a TPU, interpreted elsewhere) -----
+interp = not ops.on_tpu()
+hist = ops.histogram(x, interpret=interp)
 print(f"exp_histogram kernel: {int(hist.sum())} values binned "
       f"(== {x.size})")
 w = jnp.asarray(rng.normal(0, 0.02, (256, 512)), jnp.bfloat16)
 sm, pl, d, nesc = ops.compress_weight(w)
-out = ops.matmul_compressed(x[:64, :256], sm, pl, d)
+out = ops.matmul_compressed(x[:64, :256], sm, pl, d, interpret=interp)
 ref = jnp.dot(x[:64, :256], w, preferred_element_type=jnp.float32)
 print(f"decompress_matmul max err vs plain matmul: "
       f"{float(jnp.max(jnp.abs(out - ref))):.2e} (K-block accum order only)")

@@ -67,20 +67,10 @@ DEFAULT_CODEC = CodecConfig()
 
 
 def shmap(f, mesh, in_specs, out_specs):
-    """Project-standard shard_map: vma/rep checking off (the codec's scatter
-    ops defeat replication inference; correctness is covered by tests).
-
-    Version shim: jax >= 0.6 exposes ``jax.shard_map(..., check_vma=...)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Every call site in the repo routes through here so the compat logic
-    lives in exactly one place.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """Project-standard shard_map: vma checking off (the codec's scatter
+    ops defeat replication inference; correctness is covered by tests)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _compress(x: jax.Array, cfg: CodecConfig) -> fixed.Compressed:

@@ -130,7 +130,7 @@ class PackedWeight:
     so ``lax.scan`` / indexing slice all three buffers coherently:
 
       signman   (..., K, N)       u8   sign<<7 | mantissa
-      planes    (..., k, K, N/32) u32  bit-planes of k-bit exponent codes
+      planes    (..., k, N/32, K) u32  bit-planes of k-bit exponent codes
       dict_syms (..., 2^k)        u8   per-slice exponent dictionary
 
     ``aux`` carries ``k`` and the *resolved* compute backend baked in at
@@ -173,7 +173,7 @@ def unpack_weight(pw: PackedWeight) -> jax.Array:
     decode, vmapped over any leading stack dims)."""
 
     def one(sm, pls, d):
-        codes = packing.bitplane_unpack(jnp.moveaxis(pls, 0, -2), pw.k)
+        codes = packing.bitplane_unpack(jnp.moveaxis(pls, -1, -3), pw.k)
         exp = d[codes.astype(jnp.int32)]
         return entropy.jnp_from_u16(entropy.jnp_combine(sm, exp))
 
@@ -183,17 +183,20 @@ def unpack_weight(pw: PackedWeight) -> jax.Array:
     return fn(pw.signman, pw.planes, pw.dict_syms)
 
 
-def _leaf_eligible(path: str, x, spec, tp: int) -> bool:
+def _leaf_eligible(path: str, x, spec, tp: int, n_stack: int = 0) -> bool:
     """Bulk 2-D matmul operands only.  Raw stays raw when:
 
     - it is an embedding table (consumed by gather, not matmul),
-    - it is small (dictionary overhead beats the savings), not bf16, or <2-D,
+    - it is small (dictionary overhead beats the savings), not bf16, or has
+      fewer than 2 dims beyond its ``n_stack`` scan-stacking dims (a
+      stacked per-layer bias or norm scale is a vector, not a matrix),
     - its tp-local column count breaks the 32-lane bit-plane alignment, or
     - (checked later, at pack time) any 2-D slice needs escape symbols.
     """
     if "embed" in path:
         return False
-    if not hasattr(x, "dtype") or x.dtype != jnp.bfloat16 or x.ndim < 2:
+    if (not hasattr(x, "dtype") or x.dtype != jnp.bfloat16
+            or x.ndim - n_stack < 2):
         return False
     if x.shape[-2] * x.shape[-1] < MIN_COMPRESS_SIZE:
         return False
@@ -203,61 +206,111 @@ def _leaf_eligible(path: str, x, spec, tp: int) -> bool:
     return n_local % LANES == 0
 
 
-def _pack_leaf(x, max_k: int):
-    """Host-side pack of one leaf at the smallest escape-free code width
+def _row_chunks(w):
+    """(K, N) -> (K/R, R, N) with R the largest power of two <= 256 dividing
+    K: the packer walks a weight R rows at a time, so its int32
+    temporaries stay a few R x N (an LM head is 10^8+ elements)."""
+    r = next(r for r in (256, 128, 64, 32, 16, 8, 4, 2, 1)
+             if w.shape[0] % r == 0)
+    return w.reshape(-1, r, w.shape[1])
+
+
+def _exponents(rows):
+    return ((entropy.jnp_to_u16(rows) >> 7) & 0xFF).astype(jnp.int32)
+
+
+def _slice_hist(w):
+    """256-bin exponent histogram of one (K, N) slice."""
+    one = lambda rows: jnp.zeros((256,), jnp.int32).at[
+        _exponents(rows).reshape(-1)].add(1)
+    return jax.lax.map(one, _row_chunks(w)).sum(0)
+
+
+def _escapes_per_k(x, max_k: int) -> jax.Array:
+    """(max_k - 3,) worst-slice escape counts of leaf ``x`` at code widths
+    k = 4..max_k: the elements whose exponent falls outside the 2^k - 1
+    most frequent of its 2-D slice (the dictionary the packer builds)."""
+    hist = jax.lax.map(_slice_hist, x.reshape((-1,) + x.shape[-2:]))
+    top = jnp.cumsum(-jnp.sort(-hist, axis=-1), axis=-1)
+    total = hist.sum(-1)
+    return jnp.stack([(total - top[:, (1 << k) - 2]).max()
+                      for k in range(4, max_k + 1)])
+
+
+def _pack_2d(w, k: int):
+    """(K, N) bf16 -> (signman (K,N) u8, planes (k,N/32,K) u32, dict (2^k,)
+    u8): the bytes of ``kernels.ref.compress_weight_2d``, built a row
+    chunk at a time."""
+    dict_syms, enc_lut = fixed.build_dictionary(_slice_hist(w), k)
+
+    def one(rows):
+        codes = enc_lut[_exponents(rows)]
+        planes = packing.bitplane_pack(codes, k)          # (R, k, N/32)
+        return (entropy.jnp_signman(entropy.jnp_to_u16(rows)),
+                jnp.transpose(planes, (1, 2, 0)))         # (k, N/32, R)
+
+    sm, planes = jax.lax.map(one, _row_chunks(w))
+    kk, n = w.shape
+    return (sm.reshape(kk, n),
+            jnp.moveaxis(planes, 0, 2).reshape(k, n // LANES, kk), dict_syms)
+
+
+def _pack_leaf(x, max_k: int, shardings=None):
+    """On-device pack of one leaf at the smallest escape-free code width
     k ∈ {4..max_k} (weight exponent histograms are narrow, so most leaves
-    fit k=4 → 12 of 16 bits per element).  All leading-dim slices must
-    agree on k (it is leaf-level aux).  Returns ``(fields, k)`` or None if
-    even max_k would need escapes — that leaf stays raw."""
-    import numpy as np
+    fit k=4 → 12 of 16 bits per element).  All leading-dim slices share k
+    (it is leaf-level aux) and are packed one at a time (``lax.map``) to
+    bound temporaries; ``shardings`` (a PackedWeight of shardings) places
+    the packed fields where the raw leaf's spec says.  Returns
+    ``(fields, k)`` or None if even max_k would need escapes — that leaf
+    stays raw."""
+    esc = jax.device_get(jax.jit(_escapes_per_k, static_argnums=1)(x, max_k))
+    ok = [k for k, n in zip(range(4, max_k + 1), esc) if int(n) == 0]
+    if not ok:
+        return None
+    k = ok[0]
+    out_sh = (None if shardings is None else
+              (shardings.signman, shardings.planes, shardings.dict_syms))
+    return jax.jit(_pack_stack, static_argnums=1,
+                   out_shardings=out_sh)(x, k), k
 
-    from ..kernels import ref   # lazy: core must not import kernels at load
 
-    arr = np.asarray(x)
-    lead = arr.shape[:-2]
-    for k in range(4, max_k + 1):
-        sms, plss, ds = [], [], []
-        for idx in np.ndindex(*lead):
-            sm, pls, d, nesc = ref.compress_weight_2d(jnp.asarray(arr[idx]),
-                                                      k=k)
-            if int(nesc) != 0:
-                break
-            sms.append(np.asarray(sm))
-            plss.append(np.asarray(pls))
-            ds.append(np.asarray(d))
-        else:
-            def stack(parts):
-                if not lead:
-                    return jnp.asarray(parts[0])
-                return jnp.asarray(
-                    np.stack(parts).reshape(lead + parts[0].shape))
-            return (stack(sms), stack(plss), stack(ds)), k
-    return None
+def _pack_stack(x, k: int):
+    """``_pack_2d`` over every leading-dim slice of ``x``, one at a time."""
+    lead = x.shape[:-2]
+    fields = jax.lax.map(lambda w: _pack_2d(w, k),
+                         x.reshape((-1,) + x.shape[-2:]))
+    return tuple(f.reshape(lead + f.shape[1:]) for f in fields)
 
 
 def _packed_spec(spec, ndim: int, k: int, backend: str):
     """Derive the PartitionSpec node for a packed leaf from the raw leaf's
-    spec: signman keeps it, planes gain an unsharded ``k`` axis before K
-    (the N/32 word axis shards exactly like N — eligibility guarantees the
-    local column count is lane-aligned), the per-slice dictionary keeps
-    only the leading stack dims.  The node's aux (k, backend) must equal
+    spec: signman keeps it, planes are (k, N/32, K) — an unsharded ``k``
+    axis, then the word axis sharded exactly like N (eligibility guarantees
+    the local column count is lane-aligned), then K — and the per-slice
+    dictionary keeps only the leading stack dims.  The node's aux (k, backend) must equal
     the param node's so shard_map's tree matching lines the specs up."""
     from jax.sharding import PartitionSpec as P
     dims = tuple(spec) if spec is not None else ()
     dims = dims + (None,) * (ndim - len(dims))
     lead, kd, nd = dims[:-2], dims[-2], dims[-1]
     return PackedWeight(P(*lead, kd, nd),
-                        P(*lead, None, kd, nd),
+                        P(*lead, None, nd, kd),
                         P(*lead, None), k, backend)
 
 
 def pack_serving_params(params: Any, pspecs: Any, *, k: int = WEIGHT_K,
-                        backend: str = "jax", tp: int = 1):
+                        backend: str = "jax", tp: int = 1, mesh=None,
+                        stacked: tuple = ()):
     """Whole-model serving param store: bulk 2-D leaves -> PackedWeight
     (escape-free LEXI-FW layout at the smallest code width ≤ ``k``),
-    everything else raw.  Returns ``(packed_params, packed_pspecs)`` with
-    spec nodes swapped to match.  Idempotent: already-packed leaves pass
-    through (disagg replicas share one params tree)."""
+    everything else raw.  Packing runs on the device that holds each leaf;
+    with ``mesh`` the packed fields are laid out by their specs on it.
+    Leaves under a top-level key in ``stacked`` carry one leading
+    scan-stacking (layer) dim.
+    Returns ``(packed_params, packed_pspecs)`` with spec nodes swapped to
+    match.  Idempotent: already-packed leaves pass through (disagg
+    replicas share one params tree)."""
     from jax.sharding import PartitionSpec as P
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         params, is_leaf=_is_packed)
@@ -274,8 +327,14 @@ def pack_serving_params(params: Any, pspecs: Any, *, k: int = WEIGHT_K,
             out_s.append(spec if _is_packed(spec)
                          else _packed_spec(spec, x.ndim, x.k, x.backend))
             continue
-        packed = (_pack_leaf(x, k)
-                  if _leaf_eligible(pstr, x, spec, tp) else None)
+        packed = None
+        n_stack = int(any(pstr.startswith(f"['{key}']") for key in stacked))
+        if _leaf_eligible(pstr, x, spec, tp, n_stack):
+            shardings = None if mesh is None else jax.tree_util.tree_map(
+                lambda sp: jax.sharding.NamedSharding(mesh, sp),
+                _packed_spec(spec, x.ndim, k, backend),
+                is_leaf=lambda sp: isinstance(sp, P))
+            packed = _pack_leaf(x, k, shardings)
         if packed is None:
             out_p.append(x)
             out_s.append(spec)

@@ -4,8 +4,7 @@ The paper's decode-phase story fused into one kernel family: each grid step
 streams ONE compressed cache block HBM→VMEM ({sign·mantissa bytes, bit-plane
 packed exponent codes, 2^k-entry dictionary, escape side channel}), decodes
 it on the VPU, and runs one online-softmax attention step on the MXU — the
-decompressed block never touches HBM, so cache bandwidth is the packed size
-(the −16 % §Perf decode win executes HERE on real hardware).
+decompressed block never touches HBM, so cache bandwidth is the packed size.
 
 Two entry points share the decode + attend body:
 
@@ -17,40 +16,44 @@ Two entry points share the decode + attend body:
 ``decode_attend_paged`` — paged store (``models.cache.PagedKV``), the
     continuous-batching serving path.  **Page-table calling convention**:
     the kernel reads through per-slot page-id indirection — ``page_ids``
-    (S, maxp + 1) int32 is a scalar-prefetch operand, and the BlockSpec
+    (S, maxp) int32 is a scalar-prefetch operand, and the BlockSpec
     index_map of every compressed field is ``lambda s, i, pids, ...:
     pids[s, i]``, so the DMA engine fetches slot ``s``'s ``i``-th page
     directly from the page pool with no gather materialised in HBM.
-    Unmapped table entries must be clipped to a valid page id by the caller
-    (they are masked dead in-kernel); column ``maxp`` is the ring step and
-    its page id is ignored.  ``lengths`` (S,) holds per-slot token counts
-    (post-append); grid = (S, maxp + 1) with the page axis innermost, so
-    each slot's online-softmax accumulator lives in VMEM across its pages.
+    Unmapped table entries must be clipped to a valid page id by the caller;
+    the wrapper re-points dead columns at the slot's last live page so the
+    pipeline fetches nothing new for them (an unchanged block index is not
+    re-copied), and the kernel skips their decode.  ``lengths`` (S,) holds
+    per-slot token counts (post-append); grid = (S, maxp + 1) with the page
+    axis innermost, so each slot's online-softmax accumulator lives in VMEM
+    across its pages; column ``maxp`` is the ring step.
 
-Shared in-kernel features (exactly mirroring the pure-JAX oracle
-``models.cache`` scan path — see ``ref.decode_attend_ref`` /
-``ref.paged_decode_attend_ref``):
+Layouts the Mosaic compiler accepts (every block's last two dims are the
+array's own, or multiples of the (8, 128) tile):
 
-* live-slot masking from lengths: shard ``ti`` owns interleaved global
-  positions {p : p % tp == ti}; a full block ``i`` is live iff
-  ``i < loc_len // blk``; the ring covers local slots
-  [nfull*blk, loc_len).
-* windowed attention: positions must satisfy ``pos > L - 1 - window``
-  (callers pass a huge sentinel for non-windowed layers, so the mask is
-  uniform data — no retrace per layer).
-* GQA/MQA head mapping via a static per-q-head kv index table (one-hot
-  select-sum, no dynamic gather on the TPU path).
-* MLA payloads (``mla_lora`` set): the block payload IS the shared latent —
-  every query head attends the same k = (blk, lora+rope); v = k[:, :lora].
-* logit soft-capping (gemma2) with the same op order as
-  ``layers.attention_partial``.
-* escape patching: the side channel stores (position-ordered) raw exponents
-  for codes == ESCAPE, so the kernel recovers them with a cumsum rank +
-  gather from the per-block ``esc_raw`` — bit-exact with
-  ``fixed.decompress`` whenever ``n_escapes <= C`` (and identical overflow
-  behaviour beyond: dict slot ESCAPE decodes as exponent 0).
-  [TPU note: the rank gather is `jnp.take` — validated in interpret mode;
-  the compiled TPU lowering may need a one-hot rewrite, see ROADMAP.]
+* sign·mantissa bytes as (rows, W) per page, bit planes as (k, rows, W/32)
+  — the flat LEXI-FW stream (32 consecutive elements per u32 word) viewed
+  row by row, so the page pool stores them natively as (P, blk, W) and
+  (P, k, blk, W/32) (``page_plane_shape``; payloads with W % 32 != 0 keep
+  one flat plane row and decode through a reshape that only the
+  interpreter runs);
+* the exponent dictionary and the escape side channel as SMEM blocks of
+  shape (1, 1, n): the decode reads them as scalars.
+
+Decode: words are expanded to one code per lane in 32-row chunks, mapped
+through the dictionary with a 2^k-way compare-select against SMEM scalars,
+combined with the sign·mantissa bytes into bf16 bit patterns (int32), then
+the escape side channel is applied (positions are stored in stream order,
+so the walk stops at the first empty slot; each escape rewrites the
+exponent field of one element) — bit-exact with ``fixed.decompress``,
+overflow included (escapes beyond capacity keep the dictionary's ESCAPE
+slot, exponent 0).
+
+Attention per block: for every KV head, the MXU computes all query heads
+against that head's K (and P·V), and a static head mask keeps the rows
+that map to it — no per-head relayout, any GQA/MQA table.  MLA payloads
+(``mla_lora`` set) are one shared latent: k = payload, v = payload[:, :lora].
+Logit soft-capping (gemma2) follows ``layers.attention_partial``.
 
 Outputs are unnormalised partials (out f32, m, l) — merge across shards
 with ``layers.merge_partials`` exactly like the pure-JAX path.
@@ -67,190 +70,266 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38
 WINDOW_NONE = 1 << 30      # matches models.attention.GLOBAL_WINDOW
+LANES = 32                 # codes per u32 bit-plane word
+CHUNK_ROWS = 32            # rows decoded per step (the u8 sublane tile)
+VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def _iota(n: int) -> jax.Array:
-    """(n,) int32 iota via 2D broadcasted_iota (TPU needs >=2D)."""
-    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
+def page_plane_shape(rows: int, w: int, npad: int) -> tuple:
+    """(rows, words-per-row) view of one block's (k, npad/32) bit planes.
+
+    Row-aligned whenever W is a multiple of 32 (every real KV width); else
+    one flat row of npad/32 words."""
+    if w % LANES == 0:
+        return rows, w // LANES
+    return 1, npad // LANES
 
 
 # ---------------------------------------------------------------------------
 # shared kernel body pieces
 # ---------------------------------------------------------------------------
 
-def _decode_vals(sm_ref, planes_ref, dict_row, esc_ref, shape, k: int):
-    """Decode one compressed block to bf16 ``shape`` (flat size n).
-
-    planes -> codes -> dictionary exponents -> escape patch -> bf16.
-    The bit-plane stream is padded to a multiple of 32 elements (pad codes
-    are 0, never ESCAPE); the tail is decoded and discarded.  ``dict_row``
-    is this block's (2^k,) u16 exponent LUT row, sliced from the
-    whole-store LUT that the wrapper widens ONCE per kernel invocation and
-    pins in VMEM across grid steps (constant index_map — no per-step dict
-    DMA, no per-step u8->u16 widening).
-    """
-    n = 1
-    for d in shape:
-        n *= d
-    words = planes_ref[0]                               # (k, npad/32) u32
-    lane = jnp.arange(32, dtype=jnp.uint32)
-    codes = jnp.zeros(words.shape[1:] + (32,), jnp.uint32)
-    for bit in range(k):                                # unrolled
-        bits = (words[bit][:, None] >> lane) & jnp.uint32(1)
-        codes = codes | (bits << jnp.uint32(bit))
-    codes = codes.reshape(-1)[:n]
-    exp = jnp.zeros((n,), jnp.uint16)
-    for j in range(dict_row.shape[0]):                  # unrolled 2^k selects
-        exp = jnp.where(codes == jnp.uint32(j), dict_row[j], exp)
-    # escape patch: side-channel entries are position-ordered, so the r-th
-    # escape element takes esc_raw[r]; beyond capacity the dict's ESCAPE
-    # slot (exponent 0) stands, matching fixed.decompress overflow.
-    esc_code = jnp.uint32((1 << k) - 1)
-    is_esc = codes == esc_code
-    rank = jnp.cumsum(is_esc.astype(jnp.int32)) - 1
-    esc_raw = esc_ref[0]                                # (C,) u8
-    c = esc_raw.shape[0]
-    patched = jnp.take(esc_raw, jnp.clip(rank, 0, c - 1)).astype(jnp.uint16)
-    exp = jnp.where(is_esc & (rank < c), patched, exp)
-    smu = sm_ref[0].reshape(n).astype(jnp.uint16)
-    u16 = ((smu & jnp.uint16(0x80)) << 8) | (exp << 7) \
-        | (smu & jnp.uint16(0x7F))
-    return jax.lax.bitcast_convert_type(u16, jnp.bfloat16).reshape(shape)
+def _expand_codes(words, k: int):
+    """(k, r, nw) u32 bit planes -> (r, nw*32) int32 codes."""
+    r, nw = words.shape[1], words.shape[2]
+    lane = jax.lax.broadcasted_iota(jnp.uint32, (r, nw, LANES), 2)
+    codes = jnp.zeros((r, nw, LANES), jnp.uint32)
+    for b in range(k):                                  # unrolled
+        bits = (words[b][:, :, None] >> lane) & jnp.uint32(1)
+        codes = codes | (bits << jnp.uint32(b))
+    return codes.reshape(r, nw * LANES).astype(jnp.int32)
 
 
-def _split_heads(vals, h: int, hkv: int, hd: int, kv_idx, mla_lora):
-    """(..., blk, W) payload -> (k_sel, v_sel) per-query-head views.
-
-    GQA: W = 2*hkv*hd K‖V interleaved, static one-hot head table.
-    MLA: the latent is shared by all heads — k = vals, v = vals[..., :lora].
-    """
-    if mla_lora is not None:
-        return vals, vals[..., :mla_lora]
-    lead = vals.shape[:-2]
-    blk = vals.shape[-2]
-    kv = vals.reshape(lead + (blk, hkv, 2, hd))
-    kmat = kv[..., 0, :]                                # (..., blk, hkv, hd)
-    vmat = kv[..., 1, :]
-    k_sel = jnp.zeros(lead + (blk, h, hd), jnp.bfloat16)
-    v_sel = jnp.zeros(lead + (blk, h, hd), jnp.bfloat16)
-    for qh, kh in enumerate(kv_idx):                    # unrolled h selects
-        k_sel = k_sel.at[..., qh, :].set(kmat[..., kh, :])
-        v_sel = v_sel.at[..., qh, :].set(vmat[..., kh, :])
-    return k_sel, v_sel
+def _bf16_bits(codes, sm, dict_ref, k: int):
+    """codes/sm (r, w) int32 -> bf16 bit patterns (int32) via the SMEM
+    dictionary (2^k compare-selects against scalars)."""
+    exp = jnp.zeros(codes.shape, jnp.int32)
+    for j in range(1 << k):                             # unrolled
+        exp = jnp.where(codes == j, dict_ref[0, 0, j].astype(jnp.int32), exp)
+    return ((sm & 0x80) << 8) | (exp << 7) | (sm & 0x7F)
 
 
-def _block_partial(q, k_sel, v_sel, ok, scale, softcap, mla: bool):
+def _decode_block(sm_ref, planes_ref, dict_ref, escp_ref, escr_ref, bits_scr,
+                  kv_scr, *, k: int, npad: int):
+    """Decode the current compressed block into ``kv_scr`` (rows, W) bf16.
+
+    sm_ref (1, rows, W) u8; planes_ref (1, k, pr, pw) u32; dict/esc refs
+    (1, 1, n) in SMEM; bits_scr (rows, W) int32 scratch."""
+    rows, w = kv_scr.shape
+    pr = planes_ref.shape[2]
+    if pr == rows:                                      # row-aligned planes
+        chunk = CHUNK_ROWS if rows % CHUNK_ROWS == 0 else rows
+
+        def body(g, carry):
+            r0 = pl.multiple_of(g * chunk, chunk)
+            codes = _expand_codes(planes_ref[0, :, pl.ds(r0, chunk), :], k)
+            sm = sm_ref[0, pl.ds(r0, chunk), :].astype(jnp.int32)
+            bits_scr[pl.ds(r0, chunk), :] = _bf16_bits(codes, sm, dict_ref,
+                                                       k)
+            return carry
+
+        jax.lax.fori_loop(0, rows // chunk, body, 0)
+    else:                                               # flat plane row
+        codes = _expand_codes(planes_ref[0], k)[0, :rows * w]
+        sm = sm_ref[0].astype(jnp.int32)
+        bits_scr[...] = _bf16_bits(codes.reshape(rows, w), sm, dict_ref, k)
+
+    # escape side channel: position-ordered, empty slots hold npad
+    c_cap = escp_ref.shape[-1]
+
+    def esc_live(c):
+        pos = escp_ref[0, 0, jnp.minimum(c, c_cap - 1)]
+        return (c < c_cap) & (pos < npad)
+
+    def esc_patch(c):
+        pos = escp_ref[0, 0, c]
+        raw = escr_ref[0, 0, c].astype(jnp.int32)
+        r = pos // w
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+        row = bits_scr[pl.ds(r, 1), :]
+        fixed = (row & ~jnp.int32(0xFF << 7)) | (raw << 7)
+        bits_scr[pl.ds(r, 1), :] = jnp.where(lane == pos % w, fixed, row)
+        return c + 1
+
+    jax.lax.while_loop(esc_live, esc_patch, 0)
+    kv_scr[...] = jax.lax.bitcast_convert_type(
+        bits_scr[...].astype(jnp.uint16), jnp.bfloat16)
+
+
+def _live_mask(L, i, is_ring, blk: int, tp: int, ti, window):
+    """(1, blk) live mask of block ``i`` (or the ring) for one sequence of
+    ``L`` tokens — mirrors ``models.cache.stream_mask``."""
+    loc_len = jnp.maximum((L - 1 - ti) // tp + 1, 0)
+    nfull = loc_len // blk
+    base = jnp.where(is_ring, nfull * blk, i * blk)
+    # live slots end at loc_len in the ring; a full block is all-or-nothing
+    hi = jnp.where(is_ring, loc_len, jnp.where(i < nfull, base + blk, 0))
+    sl = base + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+    pos = sl * tp + ti
+    return (sl < hi) & (pos < L) & (pos > L - 1 - window)
+
+
+def _dot_nt(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _attend(kv, r0: int, blk: int, q, ok, *, hkv: int, hd: int,
+            kv_idx: tuple, scale: float, softcap, mla_lora):
     """One block's attention partial, mirroring ``layers.attention_partial``.
 
-    q (B?, H, hd); k_sel/v_sel (B?, blk, [H,] hd); ok (B?, blk) bool.
-    Returns (po (B?, H, hd_v) f32, m (B?, H), l (B?, H)).
-    """
-    if mla:
-        s = jnp.einsum("...hd,...nd->...hn", q, k_sel,
-                       preferred_element_type=jnp.float32) * scale
+    kv: ref holding the payload, rows [r0, r0 + blk) attended; q (H, hd);
+    ok (1, blk).  Returns (po (H, hd_v) f32, m (H, 1), l (H, 1))."""
+    h = q.shape[0]
+    rows = pl.ds(r0, blk)
+    if mla_lora is not None:
+        lat = kv[rows, :]
+        s = _dot_nt(q, lat) * scale
     else:
-        s = jnp.einsum("...hd,...nhd->...hn", q, k_sel,
-                       preferred_element_type=jnp.float32) * scale
+        head = jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0)
+        masks = []
+        s = jnp.zeros((h, blk), jnp.float32)
+        for kh in range(hkv):
+            qh = [x for x, y in enumerate(kv_idx) if y == kh]
+            if not qh:
+                continue
+            sel = functools.reduce(jnp.logical_or, [head == x for x in qh])
+            masks.append((kh, sel))
+            s_kh = _dot_nt(q, kv[rows, pl.ds(2 * kh * hd, hd)]) * scale
+            s = jnp.where(sel, s_kh, s)
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
-    okb = ok[..., None, :]                              # (B?, 1, blk)
-    s = jnp.where(okb, s, NEG_INF)
-    m = s.max(-1)
-    p = jnp.where(okb, jnp.exp(s - m[..., None]), 0.0)
-    l = p.sum(-1)
-    if mla:
-        po = jnp.einsum("...hn,...nd->...hd", p, v_sel.astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
+    s = jnp.where(ok, s, NEG_INF)
+    m = s.max(-1, keepdims=True)
+    p = jnp.where(ok, jnp.exp(s - m), 0.0)
+    l = p.sum(-1, keepdims=True)
+    if mla_lora is not None:
+        po = jnp.dot(p, lat[:, :mla_lora].astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
     else:
-        po = jnp.einsum("...hn,...nhd->...hd", p, v_sel.astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
+        po = jnp.zeros((h, hd), jnp.float32)
+        for kh, sel in masks:
+            v = kv[rows, pl.ds((2 * kh + 1) * hd, hd)].astype(jnp.float32)
+            po = jnp.where(sel, jnp.dot(p, v,
+                                        preferred_element_type=jnp.float32),
+                           po)
     return po, m, l
 
 
-def _accumulate(out_ref, m_ref, l_ref, po, pm, pl_, init_pred):
+def _accumulate(out_ref, m_ref, l_ref, po, pm, pl_):
     """Online-softmax merge of one partial into the output refs — the same
     arithmetic as ``models.cache.merge_partial`` so backends agree."""
-    @pl.when(init_pred)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
     m_old = m_ref[...]
     m_new = jnp.maximum(m_old, pm)
     a_old = jnp.exp(m_old - m_new)
     a_new = jnp.exp(pm - m_new)
-    out_ref[...] = out_ref[...] * a_old[..., None] + po * a_new[..., None]
+    out_ref[...] = out_ref[...] * a_old + po * a_new
     l_ref[...] = l_ref[...] * a_old + pl_ * a_new
     m_ref[...] = m_new
 
 
-def _live_masks(L, i, is_ring, blk: int, tp: int, ti, window):
-    """(blk,)-shaped live mask for block ``i`` / the ring, per slot.
+def _init(out_ref, m_ref, l_ref):
+    out_ref[...] = jnp.zeros_like(out_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    L may be a scalar (fixed store) or the final axis broadcasts over it.
-    """
-    loc_len = jnp.maximum((L - 1 - ti) // tp + 1, 0)
-    nfull = loc_len // blk
-    sl = jnp.where(is_ring, nfull * blk, i * blk)[..., None] + _iota(blk)
-    pos = sl * tp + ti
-    ok = (pos < L[..., None]) & (pos > L[..., None] - 1 - window)
-    live = jnp.where(is_ring, sl < loc_len[..., None],
-                     i < nfull[..., None])
-    return ok & live
+
+def _codec_operands(signman, planes, dicts, esc_pos, esc_raw, rows: int,
+                    w: int, k: int):
+    """Kernel-native views of a block store's compressed fields (no-ops for
+    the page pool, which is stored in these shapes)."""
+    nb = signman.shape[0]
+    npad = planes.shape[-1] * LANES if planes.ndim == 3 else \
+        planes.shape[-2] * planes.shape[-1] * LANES
+    pr, pw = page_plane_shape(rows, w, npad)
+    c = esc_raw.shape[-1]
+    return (signman.reshape(nb, rows, w), planes.reshape(nb, k, pr, pw),
+            dicts.reshape(nb, 1, -1), esc_pos.reshape(nb, 1, c),
+            esc_raw.reshape(nb, 1, c)), npad
+
+
+def _codec_specs(rows: int, w: int, k: int, pr: int, pw: int, c: int,
+                 nd: int, idx):
+    """BlockSpecs for the five compressed fields; ``idx`` maps grid
+    coordinates (+ prefetch refs) to the block index."""
+    smem = pltpu.SMEM
+    return [
+        pl.BlockSpec((1, rows, w), lambda *a: (idx(*a), 0, 0)),
+        pl.BlockSpec((1, k, pr, pw), lambda *a: (idx(*a), 0, 0, 0)),
+        pl.BlockSpec((1, 1, nd), lambda *a: (idx(*a), 0, 0),
+                     memory_space=smem),
+        pl.BlockSpec((1, 1, c), lambda *a: (idx(*a), 0, 0),
+                     memory_space=smem),
+        pl.BlockSpec((1, 1, c), lambda *a: (idx(*a), 0, 0),
+                     memory_space=smem),
+    ]
 
 
 # ---------------------------------------------------------------------------
 # fixed-batch store kernel
 # ---------------------------------------------------------------------------
 
-def _fixed_kernel(len_ref, meta_ref, q_ref, *rest, k: int, hkv: int, hd: int,
-                  kv_idx: tuple, scale: float, softcap, mla_lora, tp: int,
-                  blk: int, nblk: int, codec_on: bool):
+def _fixed_kernel(len_ref, meta_ref, q_ref, *rest, k: int, npad: int,
+                  hkv: int, hd: int, kv_idx: tuple, scale: float, softcap,
+                  mla_lora, tp: int, blk: int, nblk: int, codec_on: bool):
     if codec_on:
-        sm_ref, planes_ref, dict_ref, esc_ref, ring_ref = rest[:5]
-        out_ref, m_ref, l_ref = rest[5:]
+        (sm_ref, planes_ref, dict_ref, escp_ref, escr_ref, ring_ref,
+         out_ref, m_ref, l_ref, bits_scr, kv_scr) = rest
     else:
-        raw_ref, ring_ref = rest[:2]
-        out_ref, m_ref, l_ref = rest[2:]
-    b, h, _ = q_ref.shape
-    w = ring_ref.shape[-1]
+        raw_ref, ring_ref, out_ref, m_ref, l_ref = rest
+    b = q_ref.shape[0]
     i = pl.program_id(0)
     is_ring = i == nblk
     ti, window = meta_ref[0], meta_ref[1]
-    L = len_ref[0].reshape(())
+    L = len_ref[0]
+    ok = _live_mask(L, i, is_ring, blk, tp, ti, window)
+    live = jnp.logical_not(is_ring) & (i < (jnp.maximum(
+        (L - 1 - ti) // tp + 1, 0) // blk))
+    att = functools.partial(_attend, hkv=hkv, hd=hd, kv_idx=kv_idx,
+                            scale=scale, softcap=softcap, mla_lora=mla_lora)
 
-    if codec_on:
-        # dict_ref holds the whole store's pre-widened u16 LUT, resident in
-        # VMEM across grid steps (constant index_map) — slice this block's row
-        row = pl.load(dict_ref, (pl.ds(jnp.minimum(i, nblk - 1), 1),
-                                 pl.ds(0, dict_ref.shape[1])))[0]
-        vals = _decode_vals(sm_ref, planes_ref, row, esc_ref,
-                            (b, blk, w), k)
-    else:
-        vals = raw_ref[0]
-    vals = jnp.where(is_ring, ring_ref[...], vals)      # (b, blk, w)
+    @pl.when(i == 0)
+    def _():
+        _init(out_ref, m_ref, l_ref)
 
-    ok = _live_masks(L[None], i, is_ring, blk, tp, ti, window)  # (1, blk)
-    ok = jnp.broadcast_to(ok, (b, blk))
-    k_sel, v_sel = _split_heads(vals, h, hkv, hd, kv_idx, mla_lora)
-    po, pm, pl_ = _block_partial(q_ref[...], k_sel, v_sel, ok, scale,
-                                 softcap, mla_lora is not None)
-    _accumulate(out_ref, m_ref, l_ref, po, pm, pl_, i == 0)
+    def step(kv, base):
+        for bi in range(b):
+            po, pm, pl_ = att(kv, base + bi * blk, blk, q_ref[bi], ok)
+            _accumulate(out_ref.at[bi], m_ref.at[bi], l_ref.at[bi],
+                        po, pm, pl_)
+
+    @pl.when(live)
+    def _():
+        if codec_on:
+            _decode_block(sm_ref, planes_ref, dict_ref, escp_ref, escr_ref,
+                          bits_scr, kv_scr, k=k, npad=npad)
+            step(kv_scr, 0)
+        else:
+            for bi in range(b):
+                po, pm, pl_ = att(raw_ref.at[0, bi], 0, blk, q_ref[bi], ok)
+                _accumulate(out_ref.at[bi], m_ref.at[bi], l_ref.at[bi],
+                            po, pm, pl_)
+
+    @pl.when(is_ring)
+    def _():
+        for bi in range(b):
+            po, pm, pl_ = att(ring_ref.at[bi], 0, blk, q_ref[bi], ok)
+            _accumulate(out_ref.at[bi], m_ref.at[bi], l_ref.at[bi],
+                        po, pm, pl_)
 
 
-def decode_attend(q, signman, planes, dicts, esc_raw, raw_blocks, ring,
-                  length, ti, window, *, k: int, hkv: int, hd: int,
+def decode_attend(q, signman, planes, dicts, esc_pos, esc_raw, raw_blocks,
+                  ring, length, ti, window, *, k: int, hkv: int, hd: int,
                   kv_idx: tuple, scale: float, softcap=None, mla_lora=None,
-                  tp: int = 1, interpret: bool = True):
+                  tp: int = 1, interpret: bool = False):
     """Fused decompress+attend over a fixed-batch block store + its ring.
 
     q (B, H, hd); codec on: signman (nblk, B*blk*W) u8, planes
-    (nblk, k, n/32) u32, dicts (nblk, 2^k) u8, esc_raw (nblk, C) u8;
-    codec off: raw_blocks (nblk, B, blk, W) bf16.  ring (B, blk, W) bf16;
-    length/ti/window are traced scalars.  Returns (out (B,H,hd_v) f32
-    unnormalized, m (B,H), l (B,H)) — merge across shards with
-    ``layers.merge_partials`` as usual.
+    (nblk, k, n/32) u32, dicts (nblk, 2^k) u8, esc_pos (nblk, C) i32,
+    esc_raw (nblk, C) u8; codec off: raw_blocks (nblk, B, blk, W) bf16.
+    ring (B, blk, W) bf16; length/ti/window are traced scalars.  Returns
+    (out (B,H,hd_v) f32 unnormalized, m (B,H), l (B,H)) — merge across
+    shards with ``layers.merge_partials`` as usual.
     """
     codec_on = signman is not None
     b, h, _ = q.shape
@@ -260,149 +339,151 @@ def decode_attend(q, signman, planes, dicts, esc_raw, raw_blocks, ring,
     lens = jnp.asarray(length, jnp.int32).reshape(1)
     meta = jnp.stack([jnp.asarray(ti, jnp.int32),
                       jnp.asarray(window, jnp.int32)])
-
-    nsp = 2
+    last = lambda i, *s: jnp.minimum(i, nblk - 1)
+    q_spec = pl.BlockSpec((b, h, q.shape[-1]), lambda i, *s: (0, 0, 0))
+    ring_spec = pl.BlockSpec((b, blk, w), lambda i, *s: (0, 0, 0))
+    scratch, npad = [], 0
     if codec_on:
-        n = b * blk * w
-        # whole-store dictionary LUT, u16-widened ONCE per invocation and
-        # mapped with a constant index — it stays in VMEM across grid steps
-        # instead of being re-fetched + re-widened per block (ROADMAP
-        # "Kernels" hoist item); tiny: nblk * 2^k * 2 bytes.
-        dict_lut = dicts.astype(jnp.uint16)
-        in_specs = [
-            pl.BlockSpec((b, h, q.shape[-1]), lambda i, *s: (0, 0, 0)),
-            pl.BlockSpec((1, n), lambda i, *s: (jnp.minimum(i, nblk - 1), 0)),
-            pl.BlockSpec((1, k, planes.shape[-1]),
-                         lambda i, *s: (jnp.minimum(i, nblk - 1), 0, 0)),
-            pl.BlockSpec((nblk, dicts.shape[-1]), lambda i, *s: (0, 0)),
-            pl.BlockSpec((1, esc_raw.shape[-1]),
-                         lambda i, *s: (jnp.minimum(i, nblk - 1), 0)),
-            pl.BlockSpec((b, blk, w), lambda i, *s: (0, 0, 0)),
-        ]
-        operands = (q, signman, planes, dict_lut, esc_raw, ring)
+        fields, npad = _codec_operands(signman, planes, dicts, esc_pos,
+                                       esc_raw, b * blk, w, k)
+        _, _, pr, pw = fields[1].shape
+        in_specs = [q_spec] + _codec_specs(
+            b * blk, w, k, pr, pw, esc_raw.shape[-1], dicts.shape[-1],
+            last) + [ring_spec]
+        operands = (q, *fields, ring)
+        scratch = [pltpu.VMEM((b * blk, w), jnp.int32),
+                   pltpu.VMEM((b * blk, w), jnp.bfloat16)]
     else:
-        in_specs = [
-            pl.BlockSpec((b, h, q.shape[-1]), lambda i, *s: (0, 0, 0)),
-            pl.BlockSpec((1, b, blk, w),
-                         lambda i, *s: (jnp.minimum(i, nblk - 1), 0, 0, 0)),
-            pl.BlockSpec((b, blk, w), lambda i, *s: (0, 0, 0)),
-        ]
+        in_specs = [q_spec,
+                    pl.BlockSpec((1, b, blk, w),
+                                 lambda i, *s: (last(i), 0, 0, 0)),
+                    ring_spec]
         operands = (q, raw_blocks, ring)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,
+        num_scalar_prefetch=2,
         grid=(nblk + 1,),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((b, h, hd_v), lambda i, *s: (0, 0, 0)),
-            pl.BlockSpec((b, h), lambda i, *s: (0, 0)),
-            pl.BlockSpec((b, h), lambda i, *s: (0, 0)),
-        ])
+            pl.BlockSpec((b, h, 1), lambda i, *s: (0, 0, 0)),
+            pl.BlockSpec((b, h, 1), lambda i, *s: (0, 0, 0)),
+        ],
+        scratch_shapes=scratch)
     kern = functools.partial(
-        _fixed_kernel, k=k, hkv=hkv, hd=hd, kv_idx=tuple(kv_idx),
+        _fixed_kernel, k=k, npad=npad, hkv=hkv, hd=hd, kv_idx=tuple(kv_idx),
         scale=scale, softcap=softcap, mla_lora=mla_lora, tp=tp, blk=blk,
         nblk=nblk, codec_on=codec_on)
-    return pl.pallas_call(
+    out, m, l = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, hd_v), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(lens, meta, *operands)
+    return out, m[..., 0], l[..., 0]
 
 
 # ---------------------------------------------------------------------------
 # paged store kernel (continuous batching)
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(pid_ref, len_ref, meta_ref, q_ref, *rest, k: int, hkv: int,
-                  hd: int, kv_idx: tuple, scale: float, softcap, mla_lora,
-                  tp: int, blk: int, maxp: int, codec_on: bool):
+def _paged_kernel(pid_ref, len_ref, meta_ref, q_ref, *rest, k: int,
+                  npad: int, hkv: int, hd: int, kv_idx: tuple, scale: float,
+                  softcap, mla_lora, tp: int, blk: int, maxp: int,
+                  codec_on: bool):
     if codec_on:
-        sm_ref, planes_ref, dict_ref, esc_ref, ring_ref = rest[:5]
-        out_ref, m_ref, l_ref = rest[5:]
+        (sm_ref, planes_ref, dict_ref, escp_ref, escr_ref, ring_ref,
+         out_ref, m_ref, l_ref, bits_scr, kv_scr) = rest
     else:
-        raw_ref, ring_ref = rest[:2]
-        out_ref, m_ref, l_ref = rest[2:]
-    _, h, _ = q_ref.shape
-    w = ring_ref.shape[-1]
+        raw_ref, ring_ref, out_ref, m_ref, l_ref = rest
     s = pl.program_id(0)
     i = pl.program_id(1)
     is_ring = i == maxp
     ti, window = meta_ref[0], meta_ref[1]
-    L = len_ref[s].reshape(())
+    L = len_ref[s]
+    ok = _live_mask(L, i, is_ring, blk, tp, ti, window)
+    live = jnp.logical_not(is_ring) & (i < (jnp.maximum(
+        (L - 1 - ti) // tp + 1, 0) // blk))
+    att = functools.partial(_attend, hkv=hkv, hd=hd, kv_idx=kv_idx,
+                            scale=scale, softcap=softcap, mla_lora=mla_lora)
 
-    if codec_on:
-        # whole-pool LUT pinned in VMEM; this page's row via the prefetched
-        # page id (column maxp carries a valid dummy id, masked dead below)
-        row = pl.load(dict_ref, (pl.ds(pid_ref[s, i], 1),
-                                 pl.ds(0, dict_ref.shape[1])))[0]
-        vals = _decode_vals(sm_ref, planes_ref, row, esc_ref,
-                            (blk, w), k)
-    else:
-        vals = raw_ref[0]
-    vals = jnp.where(is_ring, ring_ref[0], vals)        # (blk, w)
+    @pl.when(i == 0)
+    def _():
+        _init(out_ref, m_ref, l_ref)
 
-    ok = _live_masks(L[None], i, is_ring, blk, tp, ti, window)[0]  # (blk,)
-    k_sel, v_sel = _split_heads(vals, h, hkv, hd, kv_idx, mla_lora)
-    po, pm, pl_ = _block_partial(q_ref[0], k_sel, v_sel, ok, scale,
-                                 softcap, mla_lora is not None)
-    _accumulate(out_ref, m_ref, l_ref, po[None], pm[None], pl_[None],
-                i == 0)
+    def step(kv):
+        po, pm, pl_ = att(kv, 0, blk, q_ref[0], ok)
+        _accumulate(out_ref.at[0], m_ref.at[0], l_ref.at[0], po, pm, pl_)
+
+    @pl.when(live)
+    def _():
+        if codec_on:
+            _decode_block(sm_ref, planes_ref, dict_ref, escp_ref, escr_ref,
+                          bits_scr, kv_scr, k=k, npad=npad)
+            step(kv_scr)
+        else:
+            step(raw_ref.at[0])
+
+    @pl.when(is_ring)
+    def _():
+        step(ring_ref.at[0])
 
 
-def decode_attend_paged(q, signman, planes, dicts, esc_raw, raw_pages, ring,
-                        page_ids, lengths, ti, window, *, k: int, hkv: int,
-                        hd: int, kv_idx: tuple, scale: float, softcap=None,
-                        mla_lora=None, tp: int = 1, interpret: bool = True):
+def decode_attend_paged(q, signman, planes, dicts, esc_pos, esc_raw,
+                        raw_pages, ring, page_ids, lengths, ti, window, *,
+                        k: int, hkv: int, hd: int, kv_idx: tuple,
+                        scale: float, softcap=None, mla_lora=None,
+                        tp: int = 1, interpret: bool = False):
     """Fused decompress+attend through a page table (see module docstring).
 
-    q (S, H, hd); page pool fields have leading n_pages; ring (S, blk, W);
+    q (S, H, hd); page pool fields have leading n_pages (signman
+    (P, blk, W) or (P, blk*W) u8, planes (P, k, *page_plane_shape) or
+    (P, k, Npad/32) u32, dicts (P, 2^k) u8, esc_pos (P, C) i32, esc_raw
+    (P, C) u8; codec off: raw_pages (P, blk, W) bf16); ring (S, blk, W);
     page_ids (S, maxp) int32 with unmapped entries ALREADY clipped to a
-    valid id (they are masked dead in-kernel); lengths (S,) post-append
-    token counts; ti/window traced scalars.  Returns per-slot partials
-    (out (S,H,hd_v) f32, m (S,H), l (S,H)).
+    valid id; lengths (S,) post-append token counts; ti/window traced
+    scalars.  Returns per-slot partials (out (S,H,hd_v) f32, m (S,H),
+    l (S,H)).
     """
     codec_on = signman is not None
     n_s, h, _ = q.shape
     blk, w = ring.shape[-2], ring.shape[-1]
     maxp = page_ids.shape[1]
     hd_v = mla_lora if mla_lora is not None else hd
-    # column maxp = ring step (page id unused; any valid id keeps DMA legal)
-    pids = jnp.concatenate(
-        [page_ids, jnp.zeros((n_s, 1), jnp.int32)], axis=1)
     lens = jnp.asarray(lengths, jnp.int32).reshape(n_s)
-    meta = jnp.stack([jnp.asarray(ti, jnp.int32),
-                      jnp.asarray(window, jnp.int32)])
-
+    ti = jnp.asarray(ti, jnp.int32)
+    meta = jnp.stack([ti, jnp.asarray(window, jnp.int32)])
+    # dead columns (and the ring column maxp) re-point at the slot's last
+    # live page: an unchanged block index is not fetched again
+    nfull = jnp.maximum((lens - 1 - ti) // tp + 1, 0) // blk
+    col = jnp.minimum(jnp.arange(maxp + 1)[None],
+                      jnp.maximum(nfull - 1, 0)[:, None])
+    pids = jnp.take_along_axis(page_ids, jnp.minimum(col, maxp - 1), axis=1)
+    page = lambda s, i, pid, *r: pid[s, i]
+    q_spec = pl.BlockSpec((1, h, q.shape[-1]), lambda s, i, *r: (s, 0, 0))
+    ring_spec = pl.BlockSpec((1, blk, w), lambda s, i, *r: (s, 0, 0))
+    scratch, npad = [], 0
     if codec_on:
-        n = blk * w
-        # whole-pool dictionary LUT, widened once per invocation + constant
-        # index_map: resident across the whole (S, maxp + 1) grid
-        dict_lut = dicts.astype(jnp.uint16)
-        in_specs = [
-            pl.BlockSpec((1, h, q.shape[-1]),
-                         lambda s, i, pid, *r: (s, 0, 0)),
-            pl.BlockSpec((1, n), lambda s, i, pid, *r: (pid[s, i], 0)),
-            pl.BlockSpec((1, k, planes.shape[-1]),
-                         lambda s, i, pid, *r: (pid[s, i], 0, 0)),
-            pl.BlockSpec((dicts.shape[0], dicts.shape[-1]),
-                         lambda s, i, pid, *r: (0, 0)),
-            pl.BlockSpec((1, esc_raw.shape[-1]),
-                         lambda s, i, pid, *r: (pid[s, i], 0)),
-            pl.BlockSpec((1, blk, w), lambda s, i, pid, *r: (s, 0, 0)),
-        ]
-        operands = (q, signman, planes, dict_lut, esc_raw, ring)
+        fields, npad = _codec_operands(signman, planes, dicts, esc_pos,
+                                       esc_raw, blk, w, k)
+        _, _, pr, pw = fields[1].shape
+        in_specs = [q_spec] + _codec_specs(
+            blk, w, k, pr, pw, esc_raw.shape[-1], dicts.shape[-1],
+            page) + [ring_spec]
+        operands = (q, *fields, ring)
+        scratch = [pltpu.VMEM((blk, w), jnp.int32),
+                   pltpu.VMEM((blk, w), jnp.bfloat16)]
     else:
-        in_specs = [
-            pl.BlockSpec((1, h, q.shape[-1]),
-                         lambda s, i, pid, *r: (s, 0, 0)),
-            pl.BlockSpec((1, blk, w),
-                         lambda s, i, pid, *r: (pid[s, i], 0, 0)),
-            pl.BlockSpec((1, blk, w), lambda s, i, pid, *r: (s, 0, 0)),
-        ]
+        in_specs = [q_spec,
+                    pl.BlockSpec((1, blk, w),
+                                 lambda s, i, pid, *r: (pid[s, i], 0, 0)),
+                    ring_spec]
         operands = (q, raw_pages, ring)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -411,19 +492,24 @@ def decode_attend_paged(q, signman, planes, dicts, esc_raw, raw_pages, ring,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, h, hd_v), lambda s, i, *r: (s, 0, 0)),
-            pl.BlockSpec((1, h), lambda s, i, *r: (s, 0)),
-            pl.BlockSpec((1, h), lambda s, i, *r: (s, 0)),
-        ])
+            pl.BlockSpec((1, h, 1), lambda s, i, *r: (s, 0, 0)),
+            pl.BlockSpec((1, h, 1), lambda s, i, *r: (s, 0, 0)),
+        ],
+        scratch_shapes=scratch)
     kern = functools.partial(
-        _paged_kernel, k=k, hkv=hkv, hd=hd, kv_idx=tuple(kv_idx),
+        _paged_kernel, k=k, npad=npad, hkv=hkv, hd=hd, kv_idx=tuple(kv_idx),
         scale=scale, softcap=softcap, mla_lora=mla_lora, tp=tp, blk=blk,
         maxp=maxp, codec_on=codec_on)
-    return pl.pallas_call(
+    out, m, l = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((n_s, h, hd_v), jnp.float32),
-            jax.ShapeDtypeStruct((n_s, h), jnp.float32),
-            jax.ShapeDtypeStruct((n_s, h), jnp.float32),
+            jax.ShapeDtypeStruct((n_s, h, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_s, h, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(pids, lens, meta, *operands)
+    return out, m[..., 0], l[..., 0]

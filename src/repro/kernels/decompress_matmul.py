@@ -9,17 +9,22 @@ the decode phase (weight-bandwidth-bound).
 
     out (M,N) f32 = x (M,K) bf16 @ W_packed (K,N)
 
-W_packed = (signman (K,N) u8, planes (k,K,N/32) u32, dict (2^k,) u8), as
-produced by ``ref.compress_weight_2d``.  Escape-free tiles only (k=6 at-rest
-weights never escape in practice; the param packer verifies at pack time).
+W_packed = (signman (K,N) u8, planes (k,N/32,K) u32, dict (2^k,) u8), as
+produced by ``ref.compress_weight_2d``: word (w, r) of plane b holds bit b
+of the codes of W[r, 32w:32w+32].  The word axis sits second-minor so a
+(bn/32, bk) tile of words is a legal Mosaic block for any N; in-kernel the
+words expand along sublanes into the transposed exponent tile (bn, bk),
+which one XLU transpose turns into the (bk, bn) tile the MXU consumes.
+The dictionary is a scalar-prefetch operand (SMEM): each code maps to its
+exponent by a 2^k-way compare-select against scalars.  Escape-free leaves
+only (the param packer picks the smallest escape-free k per leaf).
 
-Serving shapes are arbitrary (M=1 decode rows, tp-sharded N), so the wrapper
-pads every dim up to a block multiple and slices the result: padded x rows/
-columns are zero, so the padded K tail contributes exactly 0.0 to every
-accumulator (0 × decoded-garbage == 0 — padded plane words decode to
-dict[0]'s exponent with a zero mantissa, a finite value), and padded M/N
-output is sliced off.  N itself must be a multiple of 32 (the bit-plane
-lane width — a pack-time invariant of the format, not a block-shape limit).
+Serving shapes are arbitrary (M=1 decode rows, tp-sharded N): the packed
+weight is never padded or copied.  The K tile divides K (the largest
+multiple of 128 that does, else K itself), the N tile is ``bn`` with a
+partial last block (out-of-range columns compute garbage that is never
+written back), and only x is padded along M.  N must be a multiple of 32
+(the bit-plane word width — a pack-time invariant of the format).
 """
 
 from __future__ import annotations
@@ -29,27 +34,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 32
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _dm_kernel(x_ref, sm_ref, planes_ref, dict_ref, out_ref, *, k: int):
-    # --- decode W tile (bk, bn) from packed fields ---------------------------
-    sm = sm_ref[...]                                  # (bk, bn) uint8
-    words = planes_ref[...]                           # (k, bk, bn/32) uint32
-    lane = jnp.arange(LANES, dtype=jnp.uint32)
-    codes = jnp.zeros(words.shape[1:] + (LANES,), jnp.uint32)
-    for b in range(k):                                # (bk, bn/32, 32)
-        bits = (words[b][..., None] >> lane) & jnp.uint32(1)
+def _dm_kernel(dict_ref, x_ref, sm_ref, planes_ref, out_ref, *, k: int):
+    # --- decode the W tile from packed fields -------------------------------
+    words = planes_ref[...]                           # (k, bn/32, bk) u32
+    nw, bk = words.shape[1], words.shape[2]
+    sub = jax.lax.broadcasted_iota(jnp.uint32, (nw, LANES, bk), 1)
+    codes = jnp.zeros((nw, LANES, bk), jnp.uint32)
+    for b in range(k):                                # unrolled
+        bits = (words[b][:, None, :] >> sub) & jnp.uint32(1)
         codes = codes | (bits << jnp.uint32(b))
-    codes = codes.reshape(sm.shape)                   # (bk, bn)
-    # hoisted dictionary LUT (pre-widened to u16 by the wrapper, pinned in
-    # VMEM by its constant index_map): one gather replaces the former
-    # 2^k-iteration where-select — the same pattern decode_attend uses.
-    exp = jnp.take(dict_ref[...], codes.astype(jnp.int32))
-    smu = sm.astype(jnp.uint16)
-    u16 = ((smu & jnp.uint16(0x80)) << 8) | (exp << 7) | (smu & jnp.uint16(0x7F))
-    w = jax.lax.bitcast_convert_type(u16, jnp.bfloat16)
+    codes = codes.reshape(nw * LANES, bk).astype(jnp.int32)   # (bn, bk)
+    exp = jnp.zeros(codes.shape, jnp.int32)
+    for j in range(1 << k):                           # SMEM dictionary
+        exp = jnp.where(codes == j, dict_ref[j], exp)
+    sm = sm_ref[...].astype(jnp.int32)                # (bk, bn)
+    bits16 = ((sm & 0x80) << 8) | (exp.T << 7) | (sm & 0x7F)
+    w = jax.lax.bitcast_convert_type(bits16.astype(jnp.uint16), jnp.bfloat16)
 
     # --- MXU matmul with K-accumulation --------------------------------------
     @pl.when(pl.program_id(2) == 0)
@@ -60,42 +66,50 @@ def _dm_kernel(x_ref, sm_ref, planes_ref, dict_ref, out_ref, *, k: int):
                             preferred_element_type=jnp.float32)
 
 
+def _k_tile(kk: int, want: int) -> int:
+    """K tile: ``want`` if it divides K, else the largest multiple of 128
+    up to 2048 that divides K, else K itself."""
+    if kk % want == 0:
+        return want
+    for t in range(min(2048, kk) // 128 * 128, 0, -128):
+        if kk % t == 0:
+            return t
+    return kk
+
+
 @functools.partial(jax.jit,
                    static_argnames=("k", "bm", "bk", "bn", "interpret"))
 def decompress_matmul(x: jax.Array, signman: jax.Array, planes: jax.Array,
-                      dict_syms: jax.Array, *, k: int = 6, bm: int = 128,
-                      bk: int = 128, bn: int = 256,
-                      interpret: bool = True) -> jax.Array:
-    """x (M,K) bf16 @ packed W (K,N) -> (M,N) f32.  Any M/K/N (N % 32 == 0):
-    non-block-multiple dims are padded in, computed, and sliced back out."""
+                      dict_syms: jax.Array, *, k: int = 6, bm: int = 256,
+                      bk: int = 2048, bn: int = 256,
+                      interpret: bool = False) -> jax.Array:
+    """x (M,K) bf16 @ packed W (K,N) -> (M,N) f32.  Any M/K/N (N % 32 == 0);
+    ``bn`` should be a multiple of 256 when N > bn (word-tile alignment)."""
     m, kk = x.shape
-    _, n = signman.shape
+    n = signman.shape[1]
     assert n % LANES == 0, "packed N must be a multiple of 32 (bit-plane lanes)"
-    bm, bk, bn = min(bm, m), min(bk, kk), min(bn, n)
+    bm, bn = min(bm, m), min(bn, n)
+    bk = _k_tile(kk, bk)
     mp = -(-m // bm) * bm
-    kp = -(-kk // bk) * bk
-    np_ = -(-n // bn) * bn
-    if mp != m or kp != kk:
-        x = jnp.pad(x, ((0, mp - m), (0, kp - kk)))
-    if kp != kk or np_ != n:
-        signman = jnp.pad(signman, ((0, kp - kk), (0, np_ - n)))
-        planes = jnp.pad(planes, ((0, 0), (0, kp - kk),
-                                  (0, (np_ - n) // LANES)))
-    dict_lut = dict_syms.astype(jnp.uint16)
-    grid = (mp // bm, np_ // bn, kp // bk)
+    if mp != m:
+        x = jnp.pad(x, ((0, mp - m), (0, 0)))
+    grid = (mp // bm, pl.cdiv(n, bn), kk // bk)
     out = pl.pallas_call(
         functools.partial(_dm_kernel, k=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bk, bn), lambda i, j, l: (l, j)),
-            pl.BlockSpec((k, bk, bn // LANES), lambda i, j, l: (0, l, j)),
-            pl.BlockSpec((dict_lut.shape[0],), lambda i, j, l: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, l, d: (i, l)),
+                pl.BlockSpec((bk, bn), lambda i, j, l, d: (l, j)),
+                pl.BlockSpec((k, bn // LANES, bk),
+                             lambda i, j, l, d: (0, j, l)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, l, d: (i, j))),
+        out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(x, signman, planes, dict_lut)
-    if mp != m or np_ != n:
-        out = out[:m, :n]
-    return out
+    )(dict_syms.astype(jnp.int32), x, signman, planes)
+    return out[:m] if mp != m else out

@@ -41,7 +41,7 @@ def _hist_kernel(x_ref, hist_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def exp_histogram(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def exp_histogram(x: jax.Array, *, interpret: bool = False) -> jax.Array:
     """256-bin exponent histogram of a (G, B) bf16 stream -> (256,) int32."""
     g, b = x.shape
     return pl.pallas_call(

@@ -47,7 +47,7 @@ def _pack_kernel(x_ref, lut_ref, sm_ref, planes_ref, *, k: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
 def lexi_pack(x: jax.Array, enc_lut: jax.Array, *, k: int,
-              block: int = BLOCK_ELEMS, interpret: bool = True):
+              block: int = BLOCK_ELEMS, interpret: bool = False):
     """Pack a (G, B) bf16 stream. Returns (signman (G,B) u8,
     planes (G,k,B/32) u32)."""
     g, b = x.shape

@@ -45,7 +45,7 @@ def _unpack_kernel(sm_ref, planes_ref, dict_ref, x_ref, *, k: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def lexi_unpack(signman: jax.Array, planes: jax.Array, dict_syms: jax.Array,
-                *, k: int, interpret: bool = True) -> jax.Array:
+                *, k: int, interpret: bool = False) -> jax.Array:
     """Unpack (G,B) blocks back to bf16 (escape-free fast path)."""
     g, b = signman.shape
     return pl.pallas_call(

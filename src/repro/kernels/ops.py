@@ -3,8 +3,9 @@
 These are the entry points models/benchmarks use; each wrapper
 
 * reshapes arbitrary tensors into the kernels' (G, B) block layout,
-* auto-selects ``interpret=True`` off-TPU (this container is CPU-only; the
-  kernels are written for TPU and validated in interpret mode),
+* runs the compiled (Mosaic) kernel unless the caller names
+  ``interpret=True`` — the Pallas interpreter is a CPU test hook, never a
+  silent fallback: asking for a compiled kernel with no TPU present raises,
 * round-trips escapes through the jnp side channel so the overall semantics
   match ``repro.core.fixed`` exactly.
 """
@@ -32,8 +33,19 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _interpret() -> bool:
-    return not on_tpu()
+def require_tpu(what: str = "pallas") -> None:
+    """Compiled Pallas kernels target the TPU: refuse loudly elsewhere."""
+    if not on_tpu():
+        raise RuntimeError(
+            f"{what}: compiled Pallas kernels need a TPU, but JAX's default "
+            f"backend is {jax.default_backend()!r}; use the 'jax' backend, "
+            "or 'interpret' to run the kernels under the Pallas interpreter")
+
+
+def _mode(interpret: bool) -> bool:
+    if not interpret:
+        require_tpu()
+    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +56,8 @@ def _interpret() -> bool:
 # through here so fixed-batch and paged decode cannot diverge:
 #
 #   auto      -- pallas on TPU, jax elsewhere (the only sane defaults)
-#   pallas    -- the fused decompress+attend kernels, compiled (TPU)
+#   pallas    -- the fused decompress+attend kernels, compiled (TPU only:
+#                resolving it with no TPU present raises)
 #   interpret -- the same kernels under the Pallas interpreter (CPU testing:
 #                exercises the exact kernel logic, slowly)
 #   jax       -- the pure-JAX block/page scan (reference semantics)
@@ -62,6 +75,8 @@ def resolve_decode_backend(codec=None) -> str:
                          f"got {be!r}")
     if be == "auto":
         return "pallas" if on_tpu() else "jax"
+    if be == "pallas":
+        require_tpu("decode_backend='pallas'")
     return be
 
 
@@ -75,7 +90,8 @@ def resolve_decode_backend(codec=None) -> str:
 #
 #   auto      -- pallas on TPU, jax elsewhere
 #   pallas    -- fused decompress_matmul (packed tiles HBM->VMEM, decoded on
-#                the VPU, fed to the MXU; bf16 W never lands in HBM)
+#                the VPU, fed to the MXU; bf16 W never lands in HBM); TPU
+#                only, resolving it with no TPU present raises
 #   interpret -- the same kernel under the Pallas interpreter (CPU testing)
 #   jax       -- exact in-graph unpack + einsum (the CPU correctness gate:
 #                bit-identical to serving from raw bf16 weights)
@@ -93,6 +109,8 @@ def resolve_weight_backend(codec=None) -> str:
                          f"got {be!r}")
     if be == "auto":
         return "pallas" if on_tpu() else "jax"
+    if be == "pallas":
+        require_tpu("weight_backend='pallas'")
     return be
 
 
@@ -112,7 +130,7 @@ def matmul_packed(x: jax.Array, pw) -> jax.Array:
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.bfloat16)
     out = _dm(x2, pw.signman, pw.planes, pw.dict_syms, k=pw.k,
-              interpret=(be == "interpret") or _interpret())
+              interpret=_mode(be == "interpret"))
     return out.reshape(lead + (out.shape[-1],))
 
 
@@ -125,32 +143,34 @@ def _blockify(x: jax.Array, block: int) -> Tuple[jax.Array, int]:
     return flat.reshape(-1, block), n
 
 
-def histogram(x: jax.Array, *, block: int = ref.BLOCK_ELEMS) -> jax.Array:
+def histogram(x: jax.Array, *, block: int = ref.BLOCK_ELEMS,
+              interpret: bool = False) -> jax.Array:
     """256-bin exponent histogram of any bf16 tensor (Pallas).
 
     Zero-padding adds counts to bin 0 (exponent of +0.0); the wrapper
     subtracts them so the result matches ``ref.histogram_ref`` exactly.
     """
     xb, n = _blockify(x.astype(jnp.bfloat16), block)
-    hist = _hist(xb, interpret=_interpret())
+    hist = _hist(xb, interpret=_mode(interpret))
     pad = xb.size - n
     return hist.at[0].add(-pad)
 
 
 def pack(x: jax.Array, *, k: int = fixed.DEFAULT_K,
          esc_capacity: int | None = None,
-         block: int = ref.BLOCK_ELEMS) -> fixed.Compressed:
+         block: int = ref.BLOCK_ELEMS,
+         interpret: bool = False) -> fixed.Compressed:
     """Kernel-backed equivalent of ``fixed.compress`` (same Compressed)."""
     shape = tuple(x.shape)
     x = x.astype(jnp.bfloat16)
     n = x.size
     c = esc_capacity if esc_capacity is not None else max(
         n // fixed.DEFAULT_ESC_FRAC, 8)
-    hist = histogram(x, block=block)
+    hist = histogram(x, block=block, interpret=interpret)
     dict_syms, enc_lut = fixed.build_dictionary(hist, k)
     xb, _ = _blockify(x, block)
     sm_b, planes_b = _pack(xb, enc_lut, k=k, block=block,
-                           interpret=_interpret())
+                           interpret=_mode(interpret))
     g = xb.shape[0]
     signman = sm_b.reshape(-1)[:n]
     # (G, k, B/32) -> (k, G*B/32): grid-major plane order == flat group order.
@@ -175,7 +195,8 @@ def pack(x: jax.Array, *, k: int = fixed.DEFAULT_K,
                             shape=shape, k=k)
 
 
-def unpack(ct: fixed.Compressed, *, block: int = ref.BLOCK_ELEMS) -> jax.Array:
+def unpack(ct: fixed.Compressed, *, block: int = ref.BLOCK_ELEMS,
+           interpret: bool = False) -> jax.Array:
     """Kernel-backed equivalent of ``fixed.decompress``."""
     n = ct.n
     k = ct.k
@@ -185,7 +206,8 @@ def unpack(ct: fixed.Compressed, *, block: int = ref.BLOCK_ELEMS) -> jax.Array:
     planes_b = jnp.moveaxis(ct.planes.reshape(k, g, bw), 0, 1)  # (G,k,bw)
     sm = jnp.pad(ct.signman, (0, g * block - n))
     sm_b = sm.reshape(g, block)
-    xb = _unpack(sm_b, planes_b, ct.dict_syms, k=k, interpret=_interpret())
+    xb = _unpack(sm_b, planes_b, ct.dict_syms, k=k,
+                 interpret=_mode(interpret))
     out = xb.reshape(-1)[:n]
     # patch escapes: rebuild full bf16 values at the <=C escape positions
     # (gather signman clip-safe; sentinel positions drop at the scatter)
@@ -205,7 +227,8 @@ def compress_weight(w: jax.Array, *, k: int = 6):
 
 def matmul_compressed(x: jax.Array, signman: jax.Array, planes: jax.Array,
                       dict_syms: jax.Array, *, k: int = 6,
-                      bm: int = 128, bk: int = 128, bn: int = 256) -> jax.Array:
+                      bm: int = 256, bk: int = 2048, bn: int = 256,
+                      interpret: bool = False) -> jax.Array:
     """Fused just-in-time-decompress matmul (paper's near-compute decode)."""
     return _dm(x.astype(jnp.bfloat16), signman, planes, dict_syms, k=k,
-               bm=bm, bk=bk, bn=bn, interpret=_interpret())
+               bm=bm, bk=bk, bn=bn, interpret=_mode(interpret))

@@ -58,20 +58,25 @@ def decompress_matmul_ref(x: jax.Array, signman: jax.Array, planes: jax.Array,
                           dict_syms: jax.Array, k: int) -> jax.Array:
     """Oracle for ``decompress_matmul``: x (M,K) bf16 @ packed W (K,N).
 
-    ``planes`` is (k, K, N/32): row i's exponent codes are packed along N in
-    flat groups of 32 (so W tiles cleanly along both axes).
+    ``planes`` is (k, N/32, K): word (w, r) packs the codes of
+    W[r, 32w:32w+32] (row r's codes in flat groups of 32 along N, with the
+    word axis ahead of K so W tiles cleanly along both axes).
     """
-    kk, n = signman.shape
-    codes = packing.bitplane_unpack(jnp.moveaxis(planes, 0, -2), k)  # (K, N)
+    codes = weight_codes(planes, k)                               # (K, N)
     exp = dict_syms[codes.astype(jnp.int32)]
     u16 = E.jnp_combine(signman, exp)
     w = E.jnp_from_u16(u16)
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
+def weight_codes(planes: jax.Array, k: int) -> jax.Array:
+    """(..., k, N/32, K) weight bit planes -> (..., K, N) uint32 codes."""
+    return packing.bitplane_unpack(jnp.moveaxis(planes, -1, -3), k)
+
+
 def compress_weight_2d(w: jax.Array, k: int = 6):
-    """Host-side packer for matmul weights: (K,N) bf16 ->
-    (signman (K,N) u8, planes (k,K,N/32) u32, dict (2^k,) u8, n_escapes).
+    """Packer for matmul weights: (K,N) bf16 ->
+    (signman (K,N) u8, planes (k,N/32,K) u32, dict (2^k,) u8, n_escapes).
 
     k defaults to 6 for at-rest weights: a 63-symbol dictionary empirically
     covers every exponent of real weight tensors (distinct ~23), so the
@@ -89,7 +94,7 @@ def compress_weight_2d(w: jax.Array, k: int = 6):
     esc = fixed.esc_index(k)
     n_escapes = jnp.sum((codes == esc).astype(jnp.int32))
     planes = packing.bitplane_pack(codes, k)          # (K, k, N/32)
-    planes = jnp.moveaxis(planes, -2, 0)              # (k, K, N/32)
+    planes = jnp.transpose(planes, (1, 2, 0))         # (k, N/32, K)
     return signman, planes, dict_syms, n_escapes
 
 

@@ -181,7 +181,20 @@ def spawn_decode_host(model_args: Sequence[str], *, tp: int = 1,
                       ) -> Tuple[subprocess.Popen, int]:
     """Start ``--role decode --port 0 --once`` as a child process with the
     given model flags; returns ``(proc, port)`` once it prints READY.
-    Kills the child and raises on startup failure."""
+    Kills the child and raises on startup failure.
+
+    A TPU belongs to the first process that touches JAX: when this process
+    already holds one, a child that needs it would fail or hang on the
+    TPU library's lock, so that case is refused up front."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and \
+            jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "spawn_decode_host: this process already holds the TPU, so a "
+            "decode-host child could not reach it; run the decode host as "
+            "its own process (--role decode) and the driver elsewhere, or "
+            "serve both in one process (repro.serve.DisaggEngine)")
     env = dict(os.environ)
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -313,6 +326,10 @@ def main(argv=None) -> int:
                          "(local registries + per-host METRICS RPC) here")
     args = ap.parse_args(argv)
 
+    import jax
+    devs = jax.devices()
+    print(f"[host] devices: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}", flush=True)
     if args.selftest:
         return run_selftest(args)
     if args.role == "decode":
@@ -325,4 +342,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

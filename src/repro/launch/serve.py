@@ -6,6 +6,8 @@ with LEXI-compressed weights/activations/caches.
         --batch 4 --prompt-len 64 --new-tokens 32 --mesh 1x4
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --reduced \
         --continuous --requests 8 --slots 4 --mesh 1x4
+    python -m repro.launch.serve --arch qwen1.5-1.8b --no-reduced \
+        --continuous --compress-weights          # full width, all devices
 """
 
 from __future__ import annotations
@@ -30,11 +32,15 @@ from repro.serve import engine
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="shrink the model to a demo size (--no-reduced "
+                         "serves the published widths)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=32)
-    ap.add_argument("--mesh", default="1x4")
+    ap.add_argument("--mesh", default=None,
+                    help="DATAxMODEL device mesh (default: 1x<all devices>)")
     ap.add_argument("--codec", default="full",
                     choices=["full", "weights", "off"])
     ap.add_argument("--continuous", action="store_true",
@@ -87,7 +93,10 @@ def main(argv=None) -> int:
                          "(repro.serve.telemetry) as JSON here")
     args = ap.parse_args(argv)
 
-    d, m = (int(x) for x in args.mesh.split("x"))
+    devs = jax.devices()
+    print(f"[serve] devices: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}")
+    d, m = (int(x) for x in (args.mesh or f"1x{len(devs)}").split("x"))
     mesh_cfg = MeshConfig(data=d, model=m, pod=1)
     mesh = make_mesh_from_config(mesh_cfg)
     import dataclasses
@@ -220,4 +229,7 @@ def _serve_continuous(cfg, run, tp: int, args) -> int:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

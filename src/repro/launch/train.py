@@ -100,6 +100,9 @@ def main(argv=None) -> int:
                     help="inject a failure at this step once, then recover")
     args = ap.parse_args(argv)
 
+    devs = jax.devices()
+    print(f"[train] devices: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}")
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh_cfg = MeshConfig(data=d, model=m, pod=1)
     codec = {"full": CodecConfig(), "weights": CodecConfig.weights_only(),
@@ -130,4 +133,7 @@ def main(argv=None) -> int:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -35,6 +35,7 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.core import fixed, packing
 from repro.core.collectives import CodecConfig
 from repro.kernels import ops as kops
+from repro.kernels.decode_attend import page_plane_shape
 from . import layers
 from .ssm import SSMState
 
@@ -330,8 +331,8 @@ def attend_cache(cfg: ModelConfig, run: RunConfig, kv: KVBlocks,
 
     if backend != "jax":
         out, m, l = kops.decode_attend(
-            q[:, :, 0], kv.signman, kv.planes, kv.dict_syms, kv.esc_raw,
-            kv.raw_blocks, kv.ring, length, ti, win, tp=tp,
+            q[:, :, 0], kv.signman, kv.planes, kv.dict_syms, kv.esc_pos,
+            kv.esc_raw, kv.raw_blocks, kv.ring, length, ti, win, tp=tp,
             interpret=(backend == "interpret"),
             **_kernel_statics(cfg, run, q, spec))
         return layers.merge_partials(out[:, :, None, :], m[..., None],
@@ -395,8 +396,8 @@ class PagedKV(NamedTuple):
     pages the host decided hit refcount zero, while shared pages stay
     ``page_used`` until their last referencing slot releases.
     """
-    signman: Optional[jax.Array]    # (P, N) u8, N = block*W
-    planes: Optional[jax.Array]     # (P, k, Npad/32) u32
+    signman: Optional[jax.Array]    # (P, block, W) u8
+    planes: Optional[jax.Array]     # (P, k, *page_plane_shape) u32
     dict_syms: Optional[jax.Array]  # (P, 2^k) u8
     esc_pos: Optional[jax.Array]    # (P, C) i32
     esc_raw: Optional[jax.Array]    # (P, C) u8
@@ -454,8 +455,9 @@ def empty_paged_kv(cfg: ModelConfig, run: RunConfig, n_slots: int,
     ring = jnp.zeros((n_slots, blk, w), jnp.bfloat16)
     if run.codec.cache:
         return PagedKV(
-            signman=jnp.zeros((P_, n), jnp.uint8),
-            planes=jnp.zeros((P_, k, npad // 32), jnp.uint32),
+            signman=jnp.zeros((P_, blk, w), jnp.uint8),
+            planes=jnp.zeros((P_, k) + page_plane_shape(blk, w, npad),
+                             jnp.uint32),
             dict_syms=jnp.zeros((P_, 1 << k), jnp.uint8),
             esc_pos=jnp.full((P_, c), npad, jnp.int32),
             esc_raw=jnp.zeros((P_, c), jnp.uint8),
@@ -474,8 +476,10 @@ def load_pages(pkv: PagedKV, page_ids: jax.Array, blk: int, w: int,
     """
     pid = jnp.clip(page_ids, 0, None)
     if codec.cache:
+        n_s = pid.shape[0]
         ct = fixed.Compressed(
-            signman=pkv.signman[pid], planes=pkv.planes[pid],
+            signman=pkv.signman[pid].reshape(n_s, -1),
+            planes=pkv.planes[pid].reshape(n_s, codec.k, -1),
             dict_syms=pkv.dict_syms[pid], esc_pos=pkv.esc_pos[pid],
             esc_raw=pkv.esc_raw[pid],
             n_escapes=jnp.zeros(pid.shape, jnp.int32),
@@ -521,8 +525,12 @@ def append_token_paged(cfg: ModelConfig, run: RunConfig, pkv: PagedKV,
                 r, k=run.codec.k,
                 esc_capacity=run.codec.esc_capacity(r.size)))(pkv_c.ring)
             pkv_c = pkv_c._replace(
-                signman=pkv_c.signman.at[tgt].set(ct.signman, mode="drop"),
-                planes=pkv_c.planes.at[tgt].set(ct.planes, mode="drop"),
+                signman=pkv_c.signman.at[tgt].set(
+                    ct.signman.reshape((-1,) + pkv_c.signman.shape[1:]),
+                    mode="drop"),
+                planes=pkv_c.planes.at[tgt].set(
+                    ct.planes.reshape((-1,) + pkv_c.planes.shape[1:]),
+                    mode="drop"),
                 dict_syms=pkv_c.dict_syms.at[tgt].set(ct.dict_syms,
                                                       mode="drop"),
                 esc_pos=pkv_c.esc_pos.at[tgt].set(ct.esc_pos, mode="drop"),
@@ -560,8 +568,9 @@ def attend_paged(cfg: ModelConfig, run: RunConfig, pkv: PagedKV,
 
     if backend != "jax":
         out, m, l = kops.decode_attend_paged(
-            q[:, :, 0], pkv.signman, pkv.planes, pkv.dict_syms, pkv.esc_raw,
-            pkv.raw_pages, pkv.ring, jnp.clip(pkv.page_table, 0, None),
+            q[:, :, 0], pkv.signman, pkv.planes, pkv.dict_syms, pkv.esc_pos,
+            pkv.esc_raw, pkv.raw_pages, pkv.ring,
+            jnp.clip(pkv.page_table, 0, None),
             lengths, ti, win, tp=tp, interpret=(backend == "interpret"),
             **_kernel_statics(cfg, run, q, spec))
         return layers.merge_partials(out[:, :, None, :], m[..., None],
@@ -783,16 +792,18 @@ def export_sequence(pkv: PagedKV, slot, n_cols: int, length,
     pid = jnp.where(valid,
                     jnp.clip(row[jnp.clip(cols, 0, maxp - 1)], 0, None), 0)
 
-    def take(field, zero_dtype):
+    def take(field, zero_dtype, wire_shape=None):
         if field is None:
             return None
         out = field[pid]
         mask = valid.reshape((n_cols,) + (1,) * (out.ndim - 1))
-        return jnp.where(mask, out, jnp.zeros((), zero_dtype))
+        out = jnp.where(mask, out, jnp.zeros((), zero_dtype))
+        return out if wire_shape is None else out.reshape(wire_shape)
 
+    k = pkv.planes.shape[1] if pkv.planes is not None else 0
     return PageWire(
-        signman=take(pkv.signman, jnp.uint8),
-        planes=take(pkv.planes, jnp.uint32),
+        signman=take(pkv.signman, jnp.uint8, (n_cols, -1)),
+        planes=take(pkv.planes, jnp.uint32, (n_cols, k, -1)),
         dict_syms=take(pkv.dict_syms, jnp.uint8),
         esc_pos=take(pkv.esc_pos, jnp.int32),
         esc_raw=take(pkv.esc_raw, jnp.uint8),
@@ -844,9 +855,12 @@ def import_sequence(pkv: PagedKV, slot, wire: PageWire, length,
     valid = col0 + jnp.arange(n_cols) < nfull
     tgt = jnp.where(valid, pages, n_pages)           # sentinel drops
     if pkv.signman is not None:
+        pool = lambda f, v: v.reshape((n_cols,) + f.shape[1:])
         pkv = pkv._replace(
-            signman=pkv.signman.at[tgt].set(wire.signman, mode="drop"),
-            planes=pkv.planes.at[tgt].set(wire.planes, mode="drop"),
+            signman=pkv.signman.at[tgt].set(pool(pkv.signman, wire.signman),
+                                            mode="drop"),
+            planes=pkv.planes.at[tgt].set(pool(pkv.planes, wire.planes),
+                                          mode="drop"),
             dict_syms=pkv.dict_syms.at[tgt].set(wire.dict_syms, mode="drop"),
             esc_pos=pkv.esc_pos.at[tgt].set(wire.esc_pos, mode="drop"),
             esc_raw=pkv.esc_raw.at[tgt].set(wire.esc_raw, mode="drop"))

@@ -75,21 +75,28 @@ def apply_fsdp(table: Table, data_axes: Tuple[str, ...], data_size: int,
     return tmap(one, table)
 
 
-def init_params(table: Table, key: jax.Array) -> Any:
-    """Materialize real arrays (host/small-scale use: smoke tests, examples)."""
+def _init_leaf(d: PDef, key: jax.Array) -> jax.Array:
+    if d.init == "zeros":
+        return jnp.zeros(d.shape, d.dtype)
+    if d.init == "ones":
+        return jnp.ones(d.shape, d.dtype)
+    std = float(d.init.split(":")[1]) if ":" in d.init else 0.02
+    return (jax.random.normal(key, d.shape, jnp.float32) * std
+            ).astype(d.dtype)
+
+
+def init_params(table: Table, key: jax.Array, mesh=None) -> Any:
+    """Materialize real arrays from a seed.  Each leaf is built by its own
+    jitted program; with ``mesh`` it is born laid out by its spec, so no
+    device ever holds a whole sharded leaf or its f32 temporary."""
     leaves, treedef = jax.tree_util.tree_flatten(table, is_leaf=is_pdef)
     keys = jax.random.split(key, len(leaves))
     out = []
     for d, k in zip(leaves, keys):
-        if d.init == "zeros":
-            a = jnp.zeros(d.shape, d.dtype)
-        elif d.init == "ones":
-            a = jnp.ones(d.shape, d.dtype)
-        else:
-            std = float(d.init.split(":")[1]) if ":" in d.init else 0.02
-            a = (jax.random.normal(k, d.shape, jnp.float32) * std
-                 ).astype(d.dtype)
-        out.append(a)
+        sharding = (None if mesh is None else
+                    jax.sharding.NamedSharding(mesh, d.partition_spec()))
+        out.append(jax.jit(_init_leaf, static_argnums=0,
+                           out_shardings=sharding)(d, k))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -98,7 +98,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import MeshConfig, ModelConfig, RunConfig
 from repro.core import collectives as cl
@@ -328,11 +328,14 @@ class ServeEngine:
         self.prefix_sharing = bool(prefix_sharing and cfg.n_heads > 0
                                    and cfg.moe is None and cfg.mla is None)
         mesh_cfg = MeshConfig(data=1, model=tp, pod=1)
-        self.mesh = jax.make_mesh((1, tp), ("data", "model"))
+        self.mesh = jax.make_mesh(
+            (1, tp), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         self.table = lm.lm_table(cfg, mesh_cfg, run)
         self.dims = lm.lm_fsdp_dims(self.table)
         self.params = (params if params is not None
-                       else PM.init_params(self.table, jax.random.key(seed)))
+                       else PM.init_params(self.table, jax.random.key(seed),
+                                           mesh=self.mesh))
         self._pspecs = PM.param_pspecs(self.table)
         # serving weight plane: pack bulk 2-D leaves into the LEXI-FW
         # at-rest layout (idempotent — disagg replicas share one tree) and
@@ -343,7 +346,7 @@ class ServeEngine:
         if self.compress_weights:
             self.params, self._pspecs = weights_mod.pack_serving_params(
                 self.params, self._pspecs, backend=self.weight_backend,
-                tp=tp)
+                tp=tp, mesh=self.mesh, stacked=("blocks",))
         self._weight_bytes = weights_mod.weight_plane_bytes(self.params)
         # telemetry: the tracer is shared (a disagg fleet hands every
         # replica one tracer, distinguished by engine ``name`` = span
@@ -356,11 +359,17 @@ class ServeEngine:
         self.scheduler.tracer = self.tracer
         self.scheduler.pid = name
 
-        shard = engine.empty_paged_state(cfg, run, n_slots, max_len, tp)
+        shard = jax.eval_shape(lambda: engine.empty_paged_state(
+            cfg, run, n_slots, max_len, tp))
         self._sspec = jax.tree_util.tree_map(lambda a: P("model"), shard)
-        # global view: one leading model-sharded axis, per-shard copies
-        self.state = jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (tp,) + a.shape), shard)
+        # global view: one leading model-sharded axis, per-shard copies,
+        # each built on the device that holds its shard
+        self.state = jax.jit(
+            lambda: jax.tree_util.tree_map(
+                lambda a: jnp.broadcast_to(a[None], (tp,) + a.shape),
+                engine.empty_paged_state(cfg, run, n_slots, max_len, tp)),
+            out_shardings=jax.tree_util.tree_map(
+                lambda a: NamedSharding(self.mesh, P("model")), shard))()
 
         # tokens covered by one full page column (all shards' owned slots)
         self.blk_tokens = run.codec.cache_block * tp
@@ -1492,6 +1501,30 @@ class ServeEngine:
             decode_window_mean_s=decw["mean"],
             inter_token_mean_s=(sum(ls.decode_window_s) / steps
                                 if steps else 0.0))
+
+    def probe_logits(self, requests: List[Request]) -> np.ndarray:
+        """Admit ``requests`` (at most ``n_slots``, through the normal
+        admission path) and return the logits of the next decode step,
+        (n_slots, vocab) f32, without advancing the served state — the
+        served path's numerics, for checks against a reference engine."""
+        if len(requests) > self.n_slots:
+            raise ValueError("probe_logits admits at most n_slots requests")
+        for r in requests:
+            self.scheduler.submit(r)
+        ls = self._new_loop()
+        self._admit_phase(ls)
+
+        def step(pp, st_g, toks):
+            logits, _ = engine.paged_decode_step(
+                self.cfg, self.run_cfg, pp, self.dims, self._squeeze(st_g),
+                toks, self.tp)
+            return logits.astype(jnp.float32)
+
+        fn = jax.jit(cl.shmap(step, self.mesh,
+                              (self._pspecs, self._sspec, P(None, None)),
+                              P(None, None, "model")))
+        out = fn(self.params, self.state, jnp.asarray(ls.cur))
+        return np.asarray(out)[:, 0, :self.cfg.vocab_size]
 
     def run(self, requests: List[Request]
             ) -> Tuple[List[RequestResult], ServeStats]:

@@ -170,3 +170,8 @@ def test_resolve_decode_backend():
         CodecConfig(decode_backend="interpret")) == "interpret"
     with pytest.raises(ValueError, match="decode_backend"):
         kops.resolve_decode_backend(CodecConfig(decode_backend="nope"))
+    # compiled kernels off-TPU are refused, never silently interpreted
+    with pytest.raises(RuntimeError, match="TPU"):
+        kops.resolve_decode_backend(CodecConfig(decode_backend="pallas"))
+    with pytest.raises(RuntimeError, match="TPU"):
+        kops.histogram(jnp.zeros((8,), jnp.bfloat16))

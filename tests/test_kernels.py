@@ -36,13 +36,13 @@ class TestHistogramKernel:
                                        (1000,), (7, 321)])
     def test_matches_ref(self, shape):
         x = bf16(shape)
-        assert jnp.array_equal(ops.histogram(x),
+        assert jnp.array_equal(ops.histogram(x, interpret=True),
                                ref.histogram_ref(x.reshape(1, -1)))
 
     def test_extreme_values(self):
         x = jnp.asarray([0.0, -0.0, 1e38, -1e-38, 3.14] * 1000,
                         jnp.float32).astype(jnp.bfloat16)
-        assert jnp.array_equal(ops.histogram(x),
+        assert jnp.array_equal(ops.histogram(x, interpret=True),
                                ref.histogram_ref(x.reshape(1, -1)))
 
 
@@ -51,28 +51,28 @@ class TestPackUnpackKernels:
     @pytest.mark.parametrize("shape", [(8192,), (2, 3, 4096), (5000,)])
     def test_roundtrip(self, k, shape):
         x = bf16(shape)
-        ct = ops.pack(x, k=k)
-        assert_bits_equal(ops.unpack(ct), x)
+        ct = ops.pack(x, k=k, interpret=True)
+        assert_bits_equal(ops.unpack(ct, interpret=True), x)
 
     @pytest.mark.parametrize("k", [5, 6])
     def test_bit_compatible_with_fixed(self, k):
         """Kernel output is interchangeable with the pure-JAX codec."""
         x = bf16((3, 4096))
-        ct_k = ops.pack(x, k=k)
+        ct_k = ops.pack(x, k=k, interpret=True)
         ct_f = fixed.compress(x, k=k)
         for name in ("signman", "planes", "dict_syms", "esc_pos", "esc_raw"):
             assert jnp.array_equal(getattr(ct_k, name), getattr(ct_f, name)), name
         # cross-decode: kernel-packed -> jnp decode and vice versa
         assert_bits_equal(fixed.decompress(ct_k), x)
-        assert_bits_equal(ops.unpack(ct_f), x)
+        assert_bits_equal(ops.unpack(ct_f, interpret=True), x)
 
     def test_escapes_patch(self):
         x = np.asarray(bf16(8192), np.float32)
         x[::311] = RNG.uniform(1e28, 1e36, x[::311].shape)
         xj = jnp.asarray(x).astype(jnp.bfloat16)
-        ct = ops.pack(xj, k=4)
+        ct = ops.pack(xj, k=4, interpret=True)
         assert int(ct.n_escapes) >= 0
-        assert_bits_equal(ops.unpack(ct), xj)
+        assert_bits_equal(ops.unpack(ct, interpret=True), xj)
 
 
 class TestDecompressMatmul:
@@ -84,7 +84,7 @@ class TestDecompressMatmul:
         w = bf16((k_, n), 0.02)
         sm, pl, d, nesc = ops.compress_weight(w)
         assert int(nesc) == 0
-        out = ops.matmul_compressed(x, sm, pl, d, bm=64, bk=64, bn=128)
+        out = ops.matmul_compressed(x, sm, pl, d, bm=64, bk=64, bn=128, interpret=True)
         want = ref.decompress_matmul_ref(x, sm, pl, d, 6)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=2e-6, atol=2e-5)
@@ -93,7 +93,7 @@ class TestDecompressMatmul:
         x = bf16((64, 128), 1.0)
         w = bf16((128, 256), 0.05)
         sm, pl, d, _ = ops.compress_weight(w)
-        out = ops.matmul_compressed(x, sm, pl, d, bm=64, bk=128, bn=256)
+        out = ops.matmul_compressed(x, sm, pl, d, bm=64, bk=128, bn=256, interpret=True)
         want = jnp.dot(x, w, preferred_element_type=jnp.float32)
         assert jnp.array_equal(out, want)
 
@@ -101,7 +101,7 @@ class TestDecompressMatmul:
         w = bf16((128, 512), 0.02)
         sm, pl, d, _ = ops.compress_weight(w)
         ident = jnp.eye(128, dtype=jnp.bfloat16)
-        out = ops.matmul_compressed(ident, sm, pl, d, bm=128, bk=128, bn=256)
+        out = ops.matmul_compressed(ident, sm, pl, d, bm=128, bk=128, bn=256, interpret=True)
         assert jnp.array_equal(out.astype(jnp.bfloat16), w)
 
     @pytest.mark.parametrize("k", [4, 5, 6])
@@ -113,7 +113,7 @@ class TestDecompressMatmul:
         w = narrow_bf16((128, 256))
         sm, pl, d, nesc = ops.compress_weight(w, k=k)
         assert int(nesc) == 0
-        out = ops.matmul_compressed(x, sm, pl, d, k=k, bm=64, bk=128, bn=256)
+        out = ops.matmul_compressed(x, sm, pl, d, k=k, bm=64, bk=128, bn=256, interpret=True)
         want = ref.decompress_matmul_ref(x, sm, pl, d, k)
         assert jnp.array_equal(out, want)
 
@@ -129,7 +129,7 @@ class TestDecompressMatmul:
         w = bf16((k_, n), 0.02)
         sm, pl, d, nesc = ops.compress_weight(w)
         assert int(nesc) == 0
-        out = ops.matmul_compressed(x, sm, pl, d)
+        out = ops.matmul_compressed(x, sm, pl, d, interpret=True)
         assert out.shape == (m, n)
         want = ref.decompress_matmul_ref(x, sm, pl, d, 6)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -146,10 +146,10 @@ class TestDecompressMatmul:
         sm, pl, d, nesc = ops.compress_weight(w)
         assert int(nesc) == 0
         mesh = jax.make_mesh((tp,), ("model",))
-        f = lambda x_, sm_, pl_, d_: ops.matmul_compressed(x_, sm_, pl_, d_)
+        f = lambda x_, sm_, pl_, d_: ops.matmul_compressed(x_, sm_, pl_, d_, interpret=True)
         fj = jax.jit(cl.shmap(
             f, mesh,
-            (P(), P(None, "model"), P(None, None, "model"), P()),
+            (P(), P(None, "model"), P(None, "model", None), P()),
             P(None, "model")))
         out = fj(x, sm, pl, d)
         want = ref.decompress_matmul_ref(x, sm, pl, d, 6)
@@ -187,9 +187,9 @@ class TestDecodeAttend:
         if codec_on:
             cts = jax.vmap(lambda v: fixed.compress(v, k=5))(blocks)
             args = (q, cts.signman.reshape(nblk, -1), cts.planes,
-                    cts.dict_syms, cts.esc_raw, None, ring)
+                    cts.dict_syms, cts.esc_pos, cts.esc_raw, None, ring)
         else:
-            args = (q, None, None, None, None, blocks, ring)
+            args = (q, None, None, None, None, None, blocks, ring)
         out, m, l = decode_attend(*args, length, 0, WINDOW_NONE, k=5,
                                   hkv=hkv, hd=hd, kv_idx=kv_idx, scale=scale,
                                   tp=1, interpret=True)
@@ -213,7 +213,8 @@ class TestDecodeAttend:
                                        (4, 3, 61, 5)]:
             out, m, l = decode_attend(
                 q, cts.signman.reshape(nblk, -1), cts.planes, cts.dict_syms,
-                cts.esc_raw, None, ring, length, ti, window, k=5, hkv=hkv,
+                cts.esc_pos, cts.esc_raw, None, ring, length, ti, window,
+                k=5, hkv=hkv,
                 hd=hd, kv_idx=kv_idx, scale=scale, tp=tp, interpret=True)
             want = ref.decode_attend_ref(q, blocks, ring, length,
                                          kv_idx=kv_idx, scale=scale,
@@ -242,7 +243,8 @@ class TestDecodeAttend:
         assert int(cts.n_escapes.max()) > 0
         out, m, l = decode_attend(
             q, cts.signman.reshape(nblk, -1), cts.planes, cts.dict_syms,
-            cts.esc_raw, None, ring, nblk * blk + 2, 0, WINDOW_NONE, k=4,
+            cts.esc_pos, cts.esc_raw, None, ring, nblk * blk + 2, 0,
+            WINDOW_NONE, k=4,
             hkv=hkv, hd=hd, kv_idx=(0, 0, 1, 1), scale=hd ** -0.5, tp=1,
             interpret=True)
         want = ref.decode_attend_ref(q, blocks, ring, nblk * blk + 2,
@@ -279,10 +281,10 @@ class TestDecodeAttendPaged:
         q = bf16((n_s, h, hd), 1.0)
         if codec_on:
             cts = jax.vmap(lambda v: fixed.compress(v, k=5))(pages)
-            args = (q, cts.signman, cts.planes, cts.dict_syms, cts.esc_raw,
-                    None, ring)
+            args = (q, cts.signman, cts.planes, cts.dict_syms, cts.esc_pos,
+                    cts.esc_raw, None, ring)
         else:
-            args = (q, None, None, None, None, pages, ring)
+            args = (q, None, None, None, None, None, pages, ring)
         win = WINDOW_NONE if window is None else window
         out, m, l = decode_attend_paged(
             *args, jnp.clip(pt, 0, None), lengths, ti, win, k=5, hkv=hkv,
@@ -306,8 +308,9 @@ class TestDecodeAttendPaged:
         q = bf16((n_s, h, w), 1.0)
         cts = jax.vmap(lambda v: fixed.compress(v, k=5))(pages)
         out, m, l = decode_attend_paged(
-            q, cts.signman, cts.planes, cts.dict_syms, cts.esc_raw, None,
-            ring, jnp.clip(pt, 0, None), lengths, 0, WINDOW_NONE, k=5,
+            q, cts.signman, cts.planes, cts.dict_syms, cts.esc_pos,
+            cts.esc_raw, None, ring, jnp.clip(pt, 0, None), lengths, 0,
+            WINDOW_NONE, k=5,
             hkv=1, hd=w, kv_idx=(), scale=w ** -0.5, mla_lora=lora, tp=1,
             interpret=True)
         want = ref.paged_decode_attend_ref(

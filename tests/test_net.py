@@ -400,6 +400,19 @@ def test_socket_import_failure_keeps_pool_and_session():
 # ---------------------------------------------------------------------------
 
 
+def test_spawn_refuses_when_parent_holds_tpu(monkeypatch):
+    """A TPU belongs to the process that first touched JAX: a decode-host
+    child could never reach it, so spawning is refused before any child
+    starts (on the CPU the spawn proceeds, as the next test shows)."""
+    import jax
+    from jax._src import xla_bridge
+    from repro.launch.disagg_host import spawn_decode_host
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="already holds the TPU"):
+        spawn_decode_host(["--model", "tiny-bench"])
+
+
 def test_two_process_socket_identity():
     """The acceptance bar for the transport subsystem: a decode host in a
     SEPARATE OS process (spawned via repro.launch.disagg_host) serves
