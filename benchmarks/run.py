@@ -289,7 +289,6 @@ def bench_serving() -> None:
             "ttft_p95_ms": st.ttft_p95_s * 1e3,
             "admit_window_mean_ms": st.admit_window_mean_s * 1e3,
             "decode_window_mean_ms": st.decode_window_mean_s * 1e3,
-            "inter_token_mean_ms": st.inter_token_mean_s * 1e3,
         }
 
     scenarios = []

@@ -385,6 +385,7 @@ def decode_attend(q, signman, planes, dicts, esc_pos, esc_raw, raw_blocks,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="decode_attend",
     )(lens, meta, *operands)
     return out, m[..., 0], l[..., 0]
 
@@ -511,5 +512,6 @@ def decode_attend_paged(q, signman, planes, dicts, esc_pos, esc_raw,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="decode_attend_paged",
     )(pids, lens, meta, *operands)
     return out, m[..., 0], l[..., 0]

@@ -111,5 +111,6 @@ def decompress_matmul(x: jax.Array, signman: jax.Array, planes: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="decompress_matmul",
     )(dict_syms.astype(jnp.int32), x, signman, planes)
     return out[:m] if mp != m else out

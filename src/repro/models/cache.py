@@ -30,6 +30,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core import fixed, packing
@@ -544,7 +545,31 @@ def append_token_paged(cfg: ModelConfig, run: RunConfig, pkv: PagedKV,
         used = pkv_c.page_used.at[tgt].set(True, mode="drop")
         return pkv_c._replace(page_table=pt, page_used=used)
 
-    return jax.lax.cond(jnp.any(flush), do_flush, lambda c: c, pkv)
+    any_flush = jnp.any(flush)
+    # The scope names the flush in a profiler trace: the conditional and
+    # the ops of its taken branch, which inherit it where XLA's rewrites
+    # (the scatters become sorts) leave an op without an op_name.
+    with jax.named_scope("lexi.ring_flush"):
+        return jax.lax.cond(any_flush, do_flush, lambda c: c, pkv)
+
+
+def flush_work(lengths, active, blk: int, tp: int) -> Tuple[int, int]:
+    """(rings_filled, rings_compressed) of ``append_token_paged`` steps,
+    on the host: ``lengths``/``active`` are the slots' write positions and
+    write masks, (S,) for one step or (steps, S) summed over steps.
+
+    The rule is the device's: a written slot's ring fills when
+    ``(length // tp) % blk == blk - 1``, and a shard that sees any ring
+    fill compresses (codec off: copies) the rings of all S slots, one page
+    per slot per layer.  Counts are per step over all shards, not per
+    layer (every attention layer flushes together)."""
+    lengths = np.asarray(lengths)
+    active = np.asarray(active, bool)
+    fills = active & ((lengths // tp) % blk == blk - 1)
+    shard = lengths % tp
+    flushing = sum(int((fills & (shard == t)).any(axis=-1).sum())
+                   for t in range(tp))
+    return int(fills.sum()), flushing * lengths.shape[-1]
 
 
 def attend_paged(cfg: ModelConfig, run: RunConfig, pkv: PagedKV,

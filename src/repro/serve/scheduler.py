@@ -91,6 +91,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -184,7 +185,6 @@ class ServeStats:
     ttft_p95_s: float = 0.0
     admit_window_mean_s: float = 0.0   # batched prefill/replay dispatches
     decode_window_mean_s: float = 0.0  # fused decode dispatches
-    inter_token_mean_s: float = 0.0    # decode-window time per step
 
     @property
     def cache_ratio(self) -> float:
@@ -233,6 +233,11 @@ class _LoopState:
     replay_dispatches: int = 0
     shared_hits: int = 0
     peak_pages: int = 0
+    # admission and ring-flush work, summed over the loop's dispatches
+    prompt_tokens: int = 0            # prompt tokens of admitted requests
+    replay_tokens: int = 0            # of those, fed through replay steps
+    rings_filled: int = 0             # cache_mod.flush_work, per step
+    rings_compressed: int = 0
     # telemetry timestamps: first-token wall clocks (popped at finish /
     # export), computed TTFTs, and per-dispatch window durations — all
     # O(requests) / O(dispatches), never O(tokens)
@@ -243,6 +248,19 @@ class _LoopState:
 
     def live_slots(self) -> List[int]:
         return [s for s, r in enumerate(self.slot_req) if r is not None]
+
+
+def _engine_span(name: str, **counts):
+    """Run a ``ServeEngine`` method inside ``self.tracer.span(name)`` on
+    the engine lane."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def traced(self, *a, **kw):
+            with self.tracer.span(name, pid=self.name, tid=ENGINE_LANE,
+                                  **counts):
+                return fn(self, *a, **kw)
+        return traced
+    return wrap
 
 
 class RequestScheduler:
@@ -729,6 +747,7 @@ class ServeEngine:
                 warm.append(payloads)
         return keys, h, warm, snap
 
+    @_engine_span("page_upkeep", what="register_prefixes")
     def _register_prefixes(self, slots_prompts) -> None:
         """Index the freshly admitted slots' full page columns.
 
@@ -840,6 +859,7 @@ class ServeEngine:
                     args={"pages": self.cache.spilled_pages - p0,
                           "bytes": self.cache.spilled_bytes - b0})
 
+    @_engine_span("page_upkeep", what="free_slots")
     def _free_slots(self, slots: List[int]) -> None:
         """Evict ``slots`` through the tiered PageCache: spill last-copy
         columns to the warm store, drop the slots' references (columns at
@@ -892,6 +912,7 @@ class ServeEngine:
         return max(self._lfp(l1, t) - self._lfp(l0, t)
                    for t in range(self.tp))
 
+    @_engine_span("page_upkeep", what="ensure_free_pages")
     def _ensure_free_pages(self, need: int) -> None:
         """Make room for ``need`` fresh pages per shard/layer pool by
         evicting retained zero-ref columns (LRU order) from the hot tier.
@@ -1015,33 +1036,36 @@ class ServeEngine:
     def _finish_ready(self, ls: _LoopState) -> List[RequestResult]:
         """Harvest done slots into results and evict them; returns the
         newly finished results (the disagg router forwards them)."""
-        freed, fresh = [], []
-        for s, req in enumerate(ls.slot_req):
-            if req is None or not ls.done[s]:
-                continue
-            now = time.perf_counter()
-            ft = ls.first_tok_t.pop(req.uid, None)
-            sub = self.scheduler.submit_t.pop(req.uid, None)
-            ttft = 0.0
-            if ft is not None:
-                ttft = ft - (sub if sub is not None
-                             else ls.admit_t[req.uid])
-                ls.ttft_s[req.uid] = ttft
-            res = RequestResult(
-                uid=req.uid, prompt_len=len(req.prompt),
-                tokens=ls.emitted[req.uid][:req.max_new_tokens],
-                latency_s=now - ls.admit_t[req.uid],
-                stop_reason=ls.reason[s], ttft_s=ttft)
-            self.tracer.request_end(
-                req.uid, args={"stop_reason": res.stop_reason,
-                               "tokens": len(res.tokens)})
-            ls.results[req.uid] = res
-            fresh.append(res)
-            ls.slot_req[s] = None
-            ls.done[s], ls.reason[s] = False, ""
-            freed.append(s)
-        if freed:
-            self._free_slots(freed)
+        ready = [s for s, req in enumerate(ls.slot_req)
+                 if req is not None and ls.done[s]]
+        if not ready:
+            return []
+        fresh = []
+        with self.tracer.span("finish", pid=self.name, tid=ENGINE_LANE,
+                              finished=len(ready)):
+            for s in ready:
+                req = ls.slot_req[s]
+                now = time.perf_counter()
+                ft = ls.first_tok_t.pop(req.uid, None)
+                sub = self.scheduler.submit_t.pop(req.uid, None)
+                ttft = 0.0
+                if ft is not None:
+                    ttft = ft - (sub if sub is not None
+                                 else ls.admit_t[req.uid])
+                    ls.ttft_s[req.uid] = ttft
+                res = RequestResult(
+                    uid=req.uid, prompt_len=len(req.prompt),
+                    tokens=ls.emitted[req.uid][:req.max_new_tokens],
+                    latency_s=now - ls.admit_t[req.uid],
+                    stop_reason=ls.reason[s], ttft_s=ttft)
+                self.tracer.request_end(
+                    req.uid, args={"stop_reason": res.stop_reason,
+                                   "tokens": len(res.tokens)})
+                ls.results[req.uid] = res
+                fresh.append(res)
+                ls.slot_req[s] = None
+                ls.done[s], ls.reason[s] = False, ""
+            self._free_slots(ready)
         return fresh
 
     def _free_slot_ids(self, ls: _LoopState) -> List[int]:
@@ -1175,17 +1199,15 @@ class ServeEngine:
                                            "bucket": trunk})
         blk = self.run_cfg.codec.cache_block
         self._ensure_free_pages(len(batch) * ((trunk // self.tp) // blk))
-        t0 = tr.now()
-        toks, self.state = fn(self.params, self.state,
-                              jnp.asarray(prompts, jnp.int32),
-                              jnp.asarray(slots, jnp.int32))
+        with tr.span("admit_batch", pid=self.name, tid=ENGINE_LANE,
+                     bucket=trunk, batch=len(batch)) as sp:
+            toks, self.state = fn(self.params, self.state,
+                                  jnp.asarray(prompts, jnp.int32),
+                                  jnp.asarray(slots, jnp.int32))
+            toks = np.asarray(toks)
         ls.admit_dispatches += 1
-        toks = np.asarray(toks)
-        now = time.perf_counter()
-        ls.admit_window_s.append(now - w0)
-        tr.emit("admit_batch", cat="dispatch", pid=self.name,
-                tid=ENGINE_LANE, t0=t0, t1=tr.now(),
-                args={"bucket": trunk, "batch": len(batch)})
+        ls.admit_window_s.append(sp.seconds)
+        now = sp.end
         for j, (req, s) in enumerate(zip(batch, slots)):
             ls.slot_req[s] = req
             self._slot_busy[s] = True
@@ -1211,6 +1233,7 @@ class ServeEngine:
         comes from the step consuming its last prompt token."""
         rem = {s: tail for s, tail in replays}
         off = {s: 0 for s in rem}
+        ls.replay_tokens += sum(len(tail) for tail in rem.values())
         tr = self.tracer
         for s in rem:
             tr.stage(ls.slot_req[s].uid, "replay",
@@ -1230,18 +1253,17 @@ class ServeEngine:
                         ls.slot_len[s],
                         ls.slot_len[s] + min(k, len(rem[s]) - off[s]))
                     for s in rem))
-            t0 = tr.now()
-            w0 = time.perf_counter()
-            seq, self.state = self._replay_for(k)(
-                self.params, self.state, jnp.asarray(toks),
-                jnp.asarray(feed))
+            filled, compressed = self._flush_work(ls, feed)
+            with tr.span("replay_window", pid=self.name, tid=ENGINE_LANE,
+                         steps=k, slots=len(rem), rings_filled=filled,
+                         rings_compressed=compressed) as sp:
+                seq, self.state = self._replay_for(k)(
+                    self.params, self.state, jnp.asarray(toks),
+                    jnp.asarray(feed))
+                seq = np.asarray(seq)
             ls.replay_dispatches += 1
-            seq = np.asarray(seq)
-            now = time.perf_counter()
-            ls.admit_window_s.append(now - w0)
-            tr.emit("replay_window", cat="dispatch", pid=self.name,
-                    tid=ENGINE_LANE, t0=t0, t1=tr.now(),
-                    args={"steps": k, "slots": len(rem)})
+            ls.admit_window_s.append(sp.seconds)
+            now = sp.end
             for s in list(rem):
                 n_fed = min(k, len(rem[s]) - off[s])
                 off[s] += n_fed
@@ -1259,6 +1281,7 @@ class ServeEngine:
             if self.admit_progress_cb is not None:
                 self.admit_progress_cb(ls)   # ring flushes filled pages
 
+    @_engine_span("admit_phase")
     def _admit_phase(self, ls: _LoopState) -> None:
         """Admit until slots or admissible requests run out: shared
         prefix hits first (queue order), then one batched cold
@@ -1335,6 +1358,8 @@ class ServeEngine:
                     new_slots.extend(slots)
                     progress = True
         self._run_replays(ls, replays)
+        ls.prompt_tokens += sum(len(ls.slot_req[s].prompt)
+                                for s in new_slots)
         self._register_prefixes(
             [(s, ls.slot_req[s].prompt, ls.slot_len[s]) for s in new_slots])
         if self.prefix_sharing and self.cfg.ssm is not None:
@@ -1387,34 +1412,51 @@ class ServeEngine:
                 self._page_growth(ls.slot_len[s], ls.slot_len[s] + n_steps)
                 for s in live))
         tr = self.tracer
-        t0 = tr.now()
-        w0 = time.perf_counter()
-        seq, self.state = self._decode_for(n_steps)(
-            self.params, self.state, jnp.asarray(ls.cur))
+        fed = np.zeros((n_steps, self.n_slots), bool)
+        fed[:, live] = True
+        filled, compressed = self._flush_work(ls, fed)
+        with tr.span("decode_window", pid=self.name, tid=ENGINE_LANE,
+                     steps=n_steps, slots=len(live),
+                     weight_bytes=n_steps * self._weight_bytes[0],
+                     rings_filled=filled,
+                     rings_compressed=compressed) as sp:
+            seq, self.state = self._decode_for(n_steps)(
+                self.params, self.state, jnp.asarray(ls.cur))
+            seq = np.asarray(seq)                 # (K, n_slots, 1)
         ls.steps += n_steps
         ls.dispatches += 1
-        seq = np.asarray(seq)                     # (K, n_slots, 1)
-        ls.decode_window_s.append(time.perf_counter() - w0)
-        t1 = tr.now()
-        tr.emit("decode_window", cat="dispatch", pid=self.name,
-                tid=ENGINE_LANE, t0=t0, t1=t1,
-                args={"steps": n_steps, "slots": len(live),
-                      "weight_bytes": n_steps * self._weight_bytes[0]})
+        ls.decode_window_s.append(sp.seconds)
         if tr.enabled:
             for s in live:
-                tr.request_span(ls.slot_req[s].uid, "decode", t0=t0, t1=t1,
-                                args={"steps": n_steps})
-        for t_i in range(n_steps):
-            for s in live:
-                req = ls.slot_req[s]
-                ls.slot_len[s] += 1  # device appends even past host-done
-                if ls.done[s]:
-                    continue
-                t = int(seq[t_i, s, 0])
-                ls.emitted[req.uid].append(t)
-                ls.cur[s] = t
-                self._check_done(ls, s, req)
-            self._track_peak(ls)
+                tr.request_span(ls.slot_req[s].uid, "decode", t0=sp.t0,
+                                t1=sp.t1, args={"steps": n_steps})
+        with tr.span("token_walk", pid=self.name, tid=ENGINE_LANE):
+            for t_i in range(n_steps):
+                for s in live:
+                    req = ls.slot_req[s]
+                    ls.slot_len[s] += 1  # device appends past host-done
+                    if ls.done[s]:
+                        continue
+                    t = int(seq[t_i, s, 0])
+                    ls.emitted[req.uid].append(t)
+                    ls.cur[s] = t
+                    self._check_done(ls, s, req)
+                self._track_peak(ls)
+
+    def _flush_work(self, ls: _LoopState, fed: np.ndarray
+                    ) -> Tuple[int, int]:
+        """``cache_mod.flush_work`` of one dispatch whose step j writes
+        the slots ``fed[j]`` at their lengths + j; added to the loop's
+        totals and returned for the dispatch's span."""
+        if self.state.kv is None:
+            return 0, 0
+        lengths = (np.asarray(ls.slot_len)[None, :]
+                   + np.arange(fed.shape[0])[:, None])
+        filled, compressed = cache_mod.flush_work(
+            lengths, fed, self.run_cfg.codec.cache_block, self.tp)
+        ls.rings_filled += filled
+        ls.rings_compressed += compressed
+        return filled, compressed
 
     def sync_metrics(self, ls: _LoopState,
                      wall: Optional[float] = None) -> MetricsRegistry:
@@ -1433,6 +1475,8 @@ class ServeEngine:
         c("serve.replay_dispatches").set(ls.replay_dispatches)
         c("serve.admit_compiles").set(self.n_admit_compiles)
         c("serve.shared_page_hits").set(ls.shared_hits)
+        c("serve.prompt_tokens_admitted").set(ls.prompt_tokens)
+        c("serve.replay_tokens").set(ls.replay_tokens)
         reg.gauge("serve.peak_pages", agg="max").set(ls.peak_pages)
         reg.gauge("serve.pool_bytes").set(
             engine.paged_state_nbytes(self.state))
@@ -1440,6 +1484,8 @@ class ServeEngine:
             reg.gauge("serve.wall_s", agg="max").set(wall)
         for k, v in self.cache.counters().items():
             c(f"cache.{k}").set(v)
+        c("cache.rings_filled").set(ls.rings_filled)
+        c("cache.rings_compressed").set(ls.rings_compressed)
         reg.gauge("weights.bytes_per_step", agg="max").set(
             self._weight_bytes[0])
         reg.gauge("weights.raw_bytes_per_step", agg="max").set(
@@ -1498,9 +1544,7 @@ class ServeEngine:
             ttft_mean_s=ttft["mean"], ttft_p50_s=ttft["p50"],
             ttft_p95_s=ttft["p95"],
             admit_window_mean_s=admitw["mean"],
-            decode_window_mean_s=decw["mean"],
-            inter_token_mean_s=(sum(ls.decode_window_s) / steps
-                                if steps else 0.0))
+            decode_window_mean_s=decw["mean"])
 
     def probe_logits(self, requests: List[Request]) -> np.ndarray:
         """Admit ``requests`` (at most ``n_slots``, through the normal

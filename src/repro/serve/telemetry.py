@@ -1,8 +1,8 @@
 """Zero-device-sync tracing + unified metrics for the serving stack.
 
-Two cooperating pieces, both pure host-side (no jax imports, no device
-syncs — everything is stamped with monotonic clocks at dispatch
-boundaries that already exist):
+Two cooperating pieces, both pure host-side (no device syncs —
+everything is stamped with monotonic clocks at dispatch boundaries that
+already exist):
 
 * :class:`MetricsRegistry` — a single namespace of :class:`Counter` /
   :class:`Gauge` / :class:`Histogram` instruments.  ``ServeStats`` /
@@ -25,8 +25,11 @@ boundaries that already exist):
   recorded as *complete* events ("ph": "X") with ``perf_counter_ns``
   timestamps, exportable as Chrome trace-event JSON (Perfetto-loadable)
   via :meth:`Tracer.to_chrome_trace`.  A disabled tracer (the default)
-  turns every call into an early-out no-op, so the decode hot loop pays
-  nothing when telemetry is off.
+  turns every lifecycle call into an early-out no-op, so the decode hot
+  loop pays nothing when telemetry is off.  Engine-lane work goes through
+  :meth:`Tracer.span`, which is also a ``jax.profiler.TraceAnnotation``
+  named ``serve.<name>`` whether or not the tracer is enabled, so a
+  profiler trace shows the host's spans on the device trace's clock.
 
 Span addressing: ``pid`` is an engine name (``serve``, ``prefill0``,
 ``decode1`` — mapped to integer pids with ``process_name`` metadata on
@@ -42,6 +45,7 @@ import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 SNAPSHOT_VERSION = 1
 
@@ -257,8 +261,9 @@ class Tracer:
 
     Two layers of API:
 
-    * ``emit`` / ``span_begin`` / ``span_end`` — raw complete-span
-      plumbing for dispatch-scoped (engine-lane) events.
+    * ``span`` — engine-lane work (dispatches and the host work between
+      them), also annotated for the profiler; ``emit`` — raw
+      complete-span plumbing for spans measured around a call.
     * ``request_begin`` / ``stage`` / ``stage_end`` / ``request_end`` —
       per-uid lifecycle: one root ``request`` span per uid, with at most
       one open stage at a time (``stage`` auto-closes the previous one,
@@ -296,12 +301,14 @@ class Tracer:
                             "tid": tid, "ts": t0, "dur": max(0, t1 - t0),
                             "args": dict(args or {})})
 
-    def instant(self, name: str, *, cat: str, pid: str, tid: int,
-                args: Optional[Dict[str, Any]] = None) -> None:
-        if not self.enabled:
-            return
-        self.emit(name, cat=cat, pid=pid, tid=tid, t0=self.now(),
-                  t1=self.now(), args=args)
+    def span(self, name: str, *, pid: str, tid: int,
+             **counts) -> "Span":
+        """Context manager over one piece of engine work: always a
+        profiler annotation ``serve.<name>`` carrying ``counts`` as its
+        stats, and, when enabled, the complete event ``name`` with
+        ``counts`` as its args.  One pair of clock reads either way; the
+        yielded :class:`Span` holds them on exit (``seconds``)."""
+        return Span(self, name, pid, tid, counts)
 
     # -- request lifecycle -------------------------------------------------
 
@@ -404,3 +411,48 @@ class Tracer:
     def write(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f)
+
+
+class Span:
+    """One :meth:`Tracer.span`.  ``t0``/``t1`` are on the tracer's ns
+    clock (for request-lane spans over the same interval); ``end`` is
+    the exit time on ``time.perf_counter``'s clock, in seconds."""
+
+    __slots__ = ("_tr", "_name", "_pid", "_tid", "_counts", "_note",
+                 "_ns0", "_ns1")
+
+    def __init__(self, tracer: Tracer, name: str, pid: str, tid: int,
+                 counts: Dict[str, Any]):
+        self._tr, self._name, self._pid, self._tid = tracer, name, pid, tid
+        self._counts = counts
+        self._note = TraceAnnotation(f"serve.{name}", **counts)
+        self._ns0 = self._ns1 = 0
+
+    @property
+    def t0(self) -> int:
+        return self._ns0 - self._tr._t0
+
+    @property
+    def t1(self) -> int:
+        return self._ns1 - self._tr._t0
+
+    @property
+    def seconds(self) -> float:
+        return (self._ns1 - self._ns0) * 1e-9
+
+    @property
+    def end(self) -> float:
+        return self._ns1 * 1e-9
+
+    def __enter__(self) -> "Span":
+        self._note.__enter__()
+        self._ns0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ns1 = time.perf_counter_ns()
+        self._note.__exit__(*exc)
+        if self._tr.enabled:
+            self._tr.emit(self._name, cat="engine", pid=self._pid,
+                          tid=self._tid, t0=self.t0, t1=self.t1,
+                          args=self._counts)

@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.decode_attend import (WINDOW_NONE, decode_attend_paged,
+from repro.kernels.decode_attend import (WINDOW_NONE, decode_attend,
+                                         decode_attend_paged,
                                          page_plane_shape)
 from repro.kernels.decompress_matmul import decompress_matmul
 
@@ -53,6 +54,13 @@ def _compiled_text(fn, args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_ops(text, name):
+    """The compiled program's instructions named ``name``, as the profiler
+    shows an op: ``%name.N = ...`` without the leading ``ROOT``."""
+    lines = (ln.strip().removeprefix("ROOT ") for ln in text.splitlines())
+    return [ln for ln in lines if ln.startswith(f"%{name}.")]
+
+
 @pytest.mark.parametrize("codec_on", [True, False], ids=["codec", "raw"])
 def test_decode_attend_paged_compiles(one_chip, codec_on):
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
@@ -76,6 +84,29 @@ def test_decode_attend_paged_compiles(one_chip, codec_on):
         sd((S, BLK, W), jnp.bfloat16), sd((S, MAXP), jnp.int32),
         sd((S,), jnp.int32)))
     assert "tpu_custom_call" in text
+    # the kernel carries its name, and the benchmark's reader still finds it
+    from bench.metrics import decode_attend_paged_roofline as reader
+    (op,) = _kernel_ops(text, "decode_attend_paged")
+    assert "tpu_custom_call" in op and reader.is_kernel(op)
+
+
+def test_decode_attend_fixed_compiles(one_chip):
+    b, blk, hkv, nblk = 2, 128, 2, 4
+    w = 2 * hkv * HD
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                sharding=one_chip)
+
+    def attend(q, raw, ring, length):
+        return decode_attend(
+            q, None, None, None, None, None, raw, ring, length, 0,
+            WINDOW_NONE, k=K, hkv=hkv, hd=HD, kv_idx=(0, 0, 1, 1),
+            scale=HD ** -0.5, tp=1)
+
+    text = _compiled_text(attend, (
+        sd((b, 4, HD), jnp.bfloat16), sd((nblk, b, blk, w), jnp.bfloat16),
+        sd((b, blk, w), jnp.bfloat16), sd((), jnp.int32)))
+    (op,) = _kernel_ops(text, "decode_attend")
+    assert "tpu_custom_call" in op
 
 
 @pytest.mark.parametrize("kn", [(2048, 5504), (2048, 151936), (5504, 2048)],
@@ -90,3 +121,6 @@ def test_decompress_matmul_compiles(one_chip, kn):
         (sd((S, kk), jnp.bfloat16), sd((kk, n), jnp.uint8),
          sd((k, n // 32, kk), jnp.uint32), sd((1 << k,), jnp.uint8)))
     assert "tpu_custom_call" in text
+    from bench.metrics import decompress_matmul_roofline as reader
+    ops = _kernel_ops(text, "decompress_matmul")
+    assert ops and all(reader.is_kernel(op) for op in ops)
