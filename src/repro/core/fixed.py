@@ -122,38 +122,92 @@ def build_dictionary(hist: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     return dict_syms, enc_lut
 
 
-@functools.partial(jax.jit, static_argnames=("k", "esc_capacity"))
-def compress(x: jax.Array, *, k: int = DEFAULT_K,
-             esc_capacity: int | None = None) -> Compressed:
-    """Compress a BF16 tensor (any shape) into a :class:`Compressed`."""
+def exponent_histogram(exp: jax.Array) -> jax.Array:
+    """256-bin histogram of (n,) int32 exponents, with no scatter.
+
+    The nibble trick of ``kernels/exp_histogram.py`` in plain XLA: one-hot
+    the high and low nibbles to (16, n) int8 and contract over n with int32
+    accumulation, so ``hist[hi*16 + lo]`` is one small exact matmul.
+    """
+    nib = jnp.arange(16, dtype=jnp.int32)[:, None]
+    hi = ((exp >> 4) == nib).astype(jnp.int8)                 # (16, n)
+    lo = ((exp & 15) == nib).astype(jnp.int8)
+    counts = jnp.einsum("im,jm->ij", hi, lo,
+                        preferred_element_type=jnp.int32)
+    return counts.reshape(256)
+
+
+def encode_exponents(exp: jax.Array, dict_syms: jax.Array,
+                     enc_lut: jax.Array, k: int) -> jax.Array:
+    """``enc_lut[exp]`` as one compare-select per dictionary slot.
+
+    Slot j codes ``dict_syms[j]`` only where the LUT programs that symbol
+    to j (absent symbols, stored as 0, keep the escape), so at most one
+    slot matches a value.  Any dictionary and LUT that
+    :func:`build_dictionary` makes give the codes of the 256-entry gather
+    without reading it per value.
+    """
+    esc = esc_index(k)
+    syms = dict_syms[:esc].astype(jnp.int32)
+    live = enc_lut[syms] == jnp.arange(esc, dtype=jnp.uint32)
+    codes = jnp.full(exp.shape, esc, jnp.uint32)
+    for j in range(esc):
+        codes = jnp.where((exp == syms[j]) & live[j], jnp.uint32(j), codes)
+    return codes
+
+
+def escape_side_channel(exp: jax.Array, esc_mask: jax.Array, c: int,
+                        np_: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(esc_pos (c,), esc_raw (c,), n_escapes) of the escapes in ``esc_mask``.
+
+    Slot s holds the (s+1)-th escape: its position is where the running
+    count of escapes first reaches s+1, found by binary search, so no
+    scatter over the n values is needed.  Empty slots hold the sentinel
+    ``np_`` and raw 0; escapes past the capacity are only counted.
+    """
+    n = exp.shape[0]
+    seen = jnp.cumsum(esc_mask.astype(jnp.int32))
+    n_escapes = seen[-1]
+    want = jnp.arange(1, c + 1, dtype=jnp.int32)
+    pos = jnp.searchsorted(seen, want, side="left").astype(jnp.int32)
+    held = want <= n_escapes
+    esc_pos = jnp.where(held, pos, np_)
+    raw = exp[jnp.minimum(pos, n - 1)].astype(jnp.uint8)
+    esc_raw = jnp.where(held, raw, jnp.uint8(0))
+    return esc_pos, esc_raw, n_escapes
+
+
+def _encode(x: jax.Array, k: int, esc_capacity: int | None, dictionary=None):
+    """The shared body of :func:`compress` and :func:`compress_with_dict`."""
     shape = tuple(x.shape)
     u16 = E.jnp_to_u16(x).reshape(-1)
     n = u16.size
     np_ = packing.pad_to_lanes(n)
     c = esc_capacity if esc_capacity is not None else max(n // DEFAULT_ESC_FRAC, 8)
-    esc = esc_index(k)
-
     signman = E.jnp_signman(u16)
     exp = ((u16 >> 7) & 0xFF).astype(jnp.int32)
-    hist = jnp.zeros((256,), jnp.int32).at[exp].add(1)
-    dict_syms, enc_lut = build_dictionary(hist, k)
+    if dictionary is None:
+        dictionary = build_dictionary(exponent_histogram(exp), k)
+    dict_syms, enc_lut = dictionary
 
-    codes = enc_lut[exp]                                   # (n,) uint32
-    codes = jnp.pad(codes, (0, np_ - n))                   # pad w/ code 0
-    planes = packing.bitplane_pack(codes, k)               # (k, np/32)
-
-    esc_mask = codes[:n] == esc
-    slot = jnp.cumsum(esc_mask.astype(jnp.int32)) - 1       # slot per escape
-    n_escapes = jnp.sum(esc_mask.astype(jnp.int32))
-    write_slot = jnp.where(esc_mask & (slot < c), slot, c)  # overflow -> drop
-    esc_pos = jnp.full((c + 1,), np_, jnp.int32).at[write_slot].set(
-        jnp.arange(n, dtype=jnp.int32), mode="drop")[:c]
-    esc_raw = jnp.zeros((c + 1,), jnp.uint8).at[write_slot].set(
-        exp.astype(jnp.uint8), mode="drop")[:c]
-
+    codes = encode_exponents(exp, dict_syms, enc_lut, k)      # (n,) uint32
+    planes = packing.bitplane_pack(jnp.pad(codes, (0, np_ - n)), k)
+    esc_pos, esc_raw, n_escapes = escape_side_channel(
+        exp, codes == esc_index(k), c, np_)
     return Compressed(signman=signman, planes=planes, dict_syms=dict_syms,
                       esc_pos=esc_pos, esc_raw=esc_raw, n_escapes=n_escapes,
                       shape=shape, k=k)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "esc_capacity"))
+def compress(x: jax.Array, *, k: int = DEFAULT_K,
+             esc_capacity: int | None = None) -> Compressed:
+    """Compress a BF16 tensor (any shape) into a :class:`Compressed`.
+
+    No scatter, sort or gather runs over the values (on the TPU, XLA turns
+    a scatter into a sort over every value); the one gather reads the at
+    most ``esc_capacity`` escapes."""
+    return _encode(x, k, esc_capacity)
 
 
 @jax.jit
@@ -177,28 +231,8 @@ def decompress(ct: Compressed) -> jax.Array:
 def compress_with_dict(x: jax.Array, dict_syms: jax.Array, enc_lut: jax.Array,
                        *, k: int = DEFAULT_K,
                        esc_capacity: int | None = None) -> Compressed:
-    shape = tuple(x.shape)
-    u16 = E.jnp_to_u16(x).reshape(-1)
-    n = u16.size
-    np_ = packing.pad_to_lanes(n)
-    c = esc_capacity if esc_capacity is not None else max(n // DEFAULT_ESC_FRAC, 8)
-    esc = esc_index(k)
-    signman = E.jnp_signman(u16)
-    exp = ((u16 >> 7) & 0xFF).astype(jnp.int32)
-    codes = enc_lut[exp]
-    codes = jnp.pad(codes, (0, np_ - n))
-    planes = packing.bitplane_pack(codes, k)
-    esc_mask = codes[:n] == esc
-    slot = jnp.cumsum(esc_mask.astype(jnp.int32)) - 1
-    n_escapes = jnp.sum(esc_mask.astype(jnp.int32))
-    write_slot = jnp.where(esc_mask & (slot < c), slot, c)
-    esc_pos = jnp.full((c + 1,), np_, jnp.int32).at[write_slot].set(
-        jnp.arange(n, dtype=jnp.int32), mode="drop")[:c]
-    esc_raw = jnp.zeros((c + 1,), jnp.uint8).at[write_slot].set(
-        exp.astype(jnp.uint8), mode="drop")[:c]
-    return Compressed(signman=signman, planes=planes, dict_syms=dict_syms,
-                      esc_pos=esc_pos, esc_raw=esc_raw, n_escapes=n_escapes,
-                      shape=shape, k=k)
+    """:func:`compress` with a caller's :func:`build_dictionary` output."""
+    return _encode(x, k, esc_capacity, (dict_syms, enc_lut))
 
 
 def wire_ratio(k: int = DEFAULT_K, esc_frac: int = DEFAULT_ESC_FRAC) -> float:

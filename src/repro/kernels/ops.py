@@ -175,20 +175,14 @@ def pack(x: jax.Array, *, k: int = fixed.DEFAULT_K,
     signman = sm_b.reshape(-1)[:n]
     # (G, k, B/32) -> (k, G*B/32): grid-major plane order == flat group order.
     planes = jnp.moveaxis(planes_b, 1, 0).reshape(k, -1)
-    # escape side channel (host-of-graph jnp; rare path)
-    esc = fixed.esc_index(k)
+    # escape side channel: the same gather- and scatter-free code as
+    # ``fixed.compress``; empty slots hold the block-padded length
     u16 = E.jnp_to_u16(x).reshape(-1)
     exp = ((u16 >> 7) & 0xFF).astype(jnp.int32)
-    codes = enc_lut[exp]
-    esc_mask = codes == esc
-    slot = jnp.cumsum(esc_mask.astype(jnp.int32)) - 1
-    n_escapes = jnp.sum(esc_mask.astype(jnp.int32))
-    write_slot = jnp.where(esc_mask & (slot < c), slot, c)
-    np_ = xb.size
-    esc_pos = jnp.full((c + 1,), np_, jnp.int32).at[write_slot].set(
-        jnp.arange(n, dtype=jnp.int32), mode="drop")[:c]
-    esc_raw = jnp.zeros((c + 1,), jnp.uint8).at[write_slot].set(
-        exp.astype(jnp.uint8), mode="drop")[:c]
+    esc_mask = fixed.encode_exponents(exp, dict_syms, enc_lut,
+                                      k) == fixed.esc_index(k)
+    esc_pos, esc_raw, n_escapes = fixed.escape_side_channel(
+        exp, esc_mask, c, xb.size)
     return fixed.Compressed(signman=signman, planes=planes,
                             dict_syms=dict_syms, esc_pos=esc_pos,
                             esc_raw=esc_raw, n_escapes=n_escapes,

@@ -14,7 +14,9 @@ TPU library; the persistent compilation cache is off around these tests
 (entries written without a chip cannot be read back).
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +126,53 @@ def test_decompress_matmul_compiles(one_chip, kn):
     from bench.metrics import decompress_matmul_roofline as reader
     ops = _kernel_ops(text, "decompress_matmul")
     assert ops and all(reader.is_kernel(op) for op in ops)
+
+
+def _big_data_ops(text, big):
+    """The compiled program's sorts, scatters and gathers that move ``big``
+    or more elements: any sort or scatter with an operand or result that
+    large, and any gather whose indices or result are.  A gather's source
+    may be large, since it reads only as many elements as it has indices;
+    those are returned too, as ``(line, result elements)``, for the caller
+    to bound."""
+    shape = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
+    inst = re.compile(r"(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)"
+                      r"\((.*?)\)")
+    parsed = [m.groups() for m in map(inst.match, (
+        ln.strip() for ln in text.splitlines())) if m]
+    defs = {name: ty for name, ty, _, _ in parsed}
+
+    def elems(ty):
+        return max((math.prod(int(d) for d in dims.split(",") if d)
+                    for dims in shape.findall(ty)), default=0)
+
+    moving, reads = [], []
+    for name, ty, op, args in parsed:
+        if op not in ("sort", "scatter", "gather"):
+            continue
+        sizes = [elems(defs.get(a, "")) for a in re.findall(r"%([\w.\-]+)",
+                                                            args)]
+        if op == "gather":
+            if max(elems(ty), sizes[1]) >= big:
+                moving.append(f"{name}: {ty}")
+            elif sizes[0] >= big:
+                reads.append((f"{name}: {ty}", elems(ty)))
+        elif max([elems(ty)] + sizes) >= big:
+            moving.append(f"{name}: {ty}")
+    return moving, reads
+
+
+def test_ring_flush_encoder_moves_no_value_through_sort_scatter_gather(
+        one_chip):
+    """The flush's ``vmap(fixed.compress)`` over S rings of BLK x W values
+    compiles with no sort, scatter or gather over the values: XLA lowers
+    those to sorts over every value on the TPU.  What reads a whole ring
+    by index is the escape side channel, at most C values per ring."""
+    from repro.core import fixed
+    c = N_PAGE // 128                  # CodecConfig.esc_capacity
+    text = _compiled_text(
+        jax.vmap(lambda r: fixed.compress(r, k=K, esc_capacity=c)),
+        (jax.ShapeDtypeStruct((S, BLK, W), jnp.bfloat16, sharding=one_chip),))
+    moving, reads = _big_data_ops(text, N_PAGE)
+    assert not moving, moving[:3]
+    assert all(n <= S * c for _, n in reads), reads
